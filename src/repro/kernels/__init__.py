@@ -1,22 +1,8 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-"""Version-compat shims for the Pallas TPU API.
+"""Pallas TPU kernels (flash attention, Mamba-2 SSD) behind ``use_pallas``.
 
-``jax.experimental.pallas.tpu`` renamed ``TPUCompilerParams`` to
-``CompilerParams`` across JAX releases; this environment ships the older
-spelling.  Kernels import :func:`tpu_compiler_params` instead of touching
-either class directly, so they lower on both API generations.
+``ops`` holds the jit'd wrappers the models call, ``ref`` the pure-jnp
+oracles the kernel tests compare against.
 """
-from __future__ import annotations
-
-from jax.experimental.pallas import tpu as pltpu
-
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
-
-def tpu_compiler_params(**kwargs):
-    """Construct the TPU compiler-params object under either JAX spelling."""
-    return _COMPILER_PARAMS_CLS(**kwargs)

@@ -1,9 +1,11 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True on CPU (the container target) and False on
-real TPU — pass explicitly to override.  These are the functions the model
-zoo calls when ``use_pallas`` is enabled; each has a pure-jnp oracle in
-kernels/ref.py with identical signature/semantics.
+``interpret`` defaults to False when JAX's default backend is a TPU (the
+kernels compile through Mosaic) and to True elsewhere, where the tests run
+them in interpret mode — pass explicitly to override.  These are the
+functions the models call when ``use_pallas`` is enabled (the SSD scan via
+``models.ssm.ssd_pallas``, which adds its backward pass); each has a
+pure-jnp oracle in kernels/ref.py with identical signature/semantics.
 """
 from __future__ import annotations
 

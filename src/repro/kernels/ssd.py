@@ -27,16 +27,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _ssd_kernel(
     x_ref,  # (Q, P)   this (b, h, chunk)'s inputs
     dt_ref,  # (Q, 1)
-    A_ref,  # (1, 1)   per-head decay scalar
+    A_ref,  # (1, H)   SMEM: per-head decay scalars, whole array
     B_ref,  # (Q, N)
     C_ref,  # (Q, N)
-    D_ref,  # (1, 1)
+    D_ref,  # (1, H)   SMEM: per-head skip scalars, whole array
     y_ref,  # (Q, P)   output
     st_ref,  # (P, N)  final-state output (written on last chunk)
     state,  # VMEM scratch (P, N) f32: the carried SSD state
@@ -44,6 +42,7 @@ def _ssd_kernel(
     chunk: int,
     n_chunks: int,
 ):
+    h = pl.program_id(1)
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -52,23 +51,31 @@ def _ssd_kernel(
 
     x = x_ref[0, 0].astype(jnp.float32)  # (Q, P)
     dt = dt_ref[0, 0].astype(jnp.float32)  # (Q, 1)
-    A = A_ref[0, 0].astype(jnp.float32)
+    A = A_ref[0, h]
     Bm = B_ref[0, 0].astype(jnp.float32)  # (Q, N)
     Cm = C_ref[0, 0].astype(jnp.float32)
 
-    a = dt * A  # (Q, 1) log-decay per step
-    a_cum = jnp.cumsum(a, axis=0)  # (Q, 1)
-    a_tot = a_cum[chunk - 1, 0]
-
-    # intra-chunk: L[i,j] = exp(a_i - a_j) for i >= j
-    seg = a_cum - a_cum.reshape(1, chunk)  # (Qi, Qj)
+    # Mosaic lowers neither cumsum nor a (Q, 1) -> (1, Q) reshape, so the
+    # prefix sum is a masked lane reduction and row forms come from
+    # transposing a (Q, Q) broadcast.
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(ii >= jj, jnp.exp(seg), 0.0)
+    causal = ii >= jj
+
+    def rows(col):  # (Q, 1) -> (Q, Q) with [i, j] = col[j]
+        return jnp.broadcast_to(col, (chunk, chunk)).T
+
+    a = dt * A  # (Q, 1) log-decay per step
+    a_cum = jnp.sum(jnp.where(causal, rows(a), 0.0), axis=1, keepdims=True)  # (Q, 1)
+    a_tot = jnp.sum(a, axis=0, keepdims=True)  # (1, 1)
+
+    # intra-chunk: L[i,j] = exp(a_i - a_j) for i >= j
+    seg = a_cum - rows(a_cum)  # (Qi, Qj)
+    L = jnp.exp(jnp.where(causal, seg, -jnp.inf))  # no inf above the diagonal
     cb = jax.lax.dot_general(
         Cm, Bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (Qi, Qj)
-    M = cb * L * dt.reshape(1, chunk)
+    M = cb * L * rows(dt)
     y = jax.lax.dot_general(
         M, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )  # (Q, P)
@@ -86,7 +93,7 @@ def _ssd_kernel(
     )  # (P, N)
     state[...] = state[...] * jnp.exp(a_tot) + su
 
-    y_ref[0, 0] = (y + x * D_ref[0, 0]).astype(y_ref.dtype)
+    y_ref[0, 0] = (y + x * D_ref[0, h]).astype(y_ref.dtype)
 
     @pl.when(ci == n_chunks - 1)
     def emit_state():
@@ -118,8 +125,12 @@ def ssd_scan(
     dtg = dt.transpose(0, 2, 1)[..., None]  # (B, H, S, 1)
     Bg = Bm.transpose(0, 2, 1, 3)  # (B, G, S, N)
     Cg = Cm.transpose(0, 2, 1, 3)
-    A2 = A.reshape(H, 1)
-    D2 = D.reshape(H, 1)
+    # Per-head scalars go to SMEM whole: a (1, 1) VMEM block of an (H, 1)
+    # array breaks the TPU's (8, 128) tiling rule, and SMEM is where scalars
+    # that steer vector math belong.
+    A2 = A.astype(jnp.float32).reshape(1, H)
+    D2 = D.astype(jnp.float32).reshape(1, H)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     grid = (B, H, nc)
     kernel = functools.partial(_ssd_kernel, chunk=chunk, n_chunks=nc)
@@ -129,10 +140,10 @@ def ssd_scan(
         in_specs=[
             pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, chunk, 1), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, c: (h, 0)),
+            smem,
             pl.BlockSpec((1, 1, chunk, N), lambda b, h, c, rep=rep: (b, h // rep, c, 0)),
             pl.BlockSpec((1, 1, chunk, N), lambda b, h, c, rep=rep: (b, h // rep, c, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, c: (h, 0)),
+            smem,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
@@ -143,7 +154,7 @@ def ssd_scan(
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -61,9 +61,11 @@ class ArchConfig:
     dtype: str = "bfloat16"
 
     # --- kernels ---------------------------------------------------------------
-    # Route attention / SSD through the Pallas TPU kernels (kernels/ops.py).
-    # On CPU the kernels run in interpret mode (slow but exact) — models
-    # default to the XLA reference path; flip on TPU or in kernel tests.
+    # Route attention / SSD forward passes through the Pallas TPU kernels
+    # (kernels/ops.py).  On a TPU they compile through Mosaic; elsewhere they
+    # run in interpret mode (slow but exact).  Off by default: models take
+    # the XLA path unless a caller turns it on, as chip_smoke.py and the
+    # kernel tests do.
     use_pallas: bool = False
 
     def __post_init__(self):

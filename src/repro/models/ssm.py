@@ -20,6 +20,7 @@ per head, B/C (B,S,G,N) with heads grouped G | H.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -94,7 +95,10 @@ def ssd_chunked(x, dt, A, Bm, Cm, D, *, chunk: int) -> Tuple[jax.Array, jax.Arra
         a_total = a_cum[:, -1, :]  # (B,H)
         # intra-chunk: L[i,j] = exp(a_i - a_j) (i>=j); scores (C_i . B_j)
         seg = a_cum[:, :, None, :] - a_cum[:, None, :, :]  # (B,Qi,Qj,H)
-        L = jnp.where(causal, jnp.exp(seg), 0.0)
+        # Mask before exp: above the diagonal seg is a positive sum of up to
+        # Q decays, exp overflows to inf, and where's backward then makes
+        # 0 * inf = NaN gradients.
+        L = jnp.exp(jnp.where(causal, seg, -jnp.inf))
         cb = jnp.einsum(
             "bign,bjgn->bijg", C_j, B_j, preferred_element_type=jnp.float32
         )  # (B,Qi,Qj,G) — inputs may be bf16; accumulate f32
@@ -128,6 +132,32 @@ def ssd_chunked(x, dt, A, Bm, Cm, D, *, chunk: int) -> Tuple[jax.Array, jax.Arra
     )
     y = jnp.moveaxis(ys, 0, 1).reshape(Bb, S, H, Pd)
     return y + x * D[None, None, :, None], final
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def ssd_pallas(x, dt, A, Bm, Cm, D, chunk: int):
+    """The Pallas SSD kernel (kernels/ssd.py) with a backward pass.
+
+    A ``pallas_call`` has no transpose rule, so the forward runs the kernel
+    and the backward differentiates ``ssd_chunked``, the XLA form of the
+    same recurrence, recomputed from the saved inputs.
+    """
+    from repro.kernels.ops import ssd_scan  # lazy: no cycle
+
+    return ssd_scan(x, dt, A, Bm, Cm, D, chunk=chunk)
+
+
+def _ssd_pallas_fwd(x, dt, A, Bm, Cm, D, chunk):
+    return ssd_pallas(x, dt, A, Bm, Cm, D, chunk), (x, dt, A, Bm, Cm, D)
+
+
+def _ssd_pallas_bwd(chunk, inputs, grads):
+    _, vjp = jax.vjp(lambda *a: ssd_chunked(*a, chunk=chunk), *inputs)
+    g_y, g_state = grads
+    return vjp((g_y.astype(jnp.float32), g_state))
+
+
+ssd_pallas.defvjp(_ssd_pallas_fwd, _ssd_pallas_bwd)
 
 
 def ssd_decode_step(state, x, dt, A, Bm, Cm, D):
@@ -215,10 +245,8 @@ def ssm_block(
     # kernel consumes on TPU.
     xh, Bg, Cg = xh.astype(dt0), Bg.astype(dt0), Cg.astype(dt0)
     if cfg.use_pallas:
-        from repro.kernels.ops import ssd_scan  # lazy: no cycle
-
-        y, ssd_state = ssd_scan(
-            xh, dtv, A, Bg, Cg, params["D"].astype(jnp.float32), chunk=chunk
+        y, ssd_state = ssd_pallas(
+            xh, dtv, A, Bg, Cg, params["D"].astype(jnp.float32), chunk
         )
         y = y.astype(jnp.float32)
     else:

@@ -156,8 +156,11 @@ def restore_checkpoint(
         raise FileNotFoundError(f"no checkpoints under {directory}")
     path = pathlib.Path(directory) / f"step_{step:08d}"
     manifest = json.loads((path / "manifest.json").read_text())
+    dtypes = manifest["dtypes"]
     with np.load(path / "arrays.npz") as z:
-        arrays = {k: z[k] for k in z.files}
+        # np.savez writes ml_dtypes leaves (bfloat16) as raw void records
+        # ('|V2'); the manifest's dtype name views the bytes back losslessly.
+        arrays = {k: z[k].view(jax.numpy.dtype(dtypes[k])) for k in z.files}
 
     def unflatten(like_tree, prefix):
         flat_paths = jax.tree_util.tree_flatten_with_path(like_tree)

@@ -128,10 +128,10 @@ class Trainer:
             for batch in self.loader:
                 dev_batch = self._to_device_batch(batch)
                 t0 = time.monotonic()
-                loss, self.params, self.opt_state = self._step_fn(
-                    self.params, self.opt_state, dev_batch
-                )
-                loss = float(loss)  # blocks; includes device compute
+                loss, self.params, self.opt_state = jax.block_until_ready(
+                    self._step_fn(self.params, self.opt_state, dev_batch)
+                )  # compute_s spans the whole device step
+                loss = float(loss)
                 compute_s = time.monotonic() - t0
                 self.step += 1
                 m = StepMetrics(

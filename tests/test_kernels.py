@@ -147,3 +147,45 @@ def test_model_forward_with_pallas_kernels_matches_xla():
     np.testing.assert_allclose(
         np.asarray(h_x, np.float32), np.asarray(h_k, np.float32), atol=2e-3, rtol=2e-3
     )
+
+
+def test_ssd_chunked_gradients_finite_at_full_chunk():
+    """Above the diagonal a 128-step chunk's decay sums overflow exp; the
+    mask must act before exp or the backward pass turns 0 * inf into NaN."""
+    from repro.models.ssm import ssd_chunked
+
+    x, dt, A, Bm, Cm, D = _ssd_inputs(jax.random.PRNGKey(6), 1, 256, 2, 16, 1, 16, jnp.float32)
+    dt = dt + 1.0  # decays of ~1.7 per step: sums reach ~-200 in a chunk
+
+    def loss(x, dt):
+        y, st = ssd_chunked(x, dt, A, Bm, Cm, D, chunk=128)
+        return y.sum() + st.sum()
+
+    for g in jax.grad(loss, argnums=(0, 1))(x, dt):
+        assert np.isfinite(np.asarray(g)).all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_ssd_pallas_gradients_match_xla_chunked(dtype):
+    """The kernel's custom VJP (backward through ssd_chunked) gives the
+    gradients of the XLA path."""
+    from repro.models.ssm import ssd_chunked, ssd_pallas
+
+    x, dt, A, Bm, Cm, D = _ssd_inputs(jax.random.PRNGKey(8), 2, 64, 2, 16, 1, 16, dtype)
+    Bm, Cm = Bm.astype(dtype), Cm.astype(dtype)
+
+    def loss(f):
+        def inner(x, dt, A, Bm, Cm, D):
+            y, st = f(x, dt, A, Bm, Cm, D)
+            return y.astype(jnp.float32).sum() + st.sum()
+        return inner
+
+    args = (x, dt, A, Bm, Cm, D)
+    g_k = jax.grad(loss(lambda *a: ssd_pallas(*a, 16)), argnums=range(6))(*args)
+    g_x = jax.grad(loss(lambda *a: ssd_chunked(*a, chunk=16)), argnums=range(6))(*args)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4
+    for a, b in zip(g_k, g_x):
+        assert a.dtype == b.dtype
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), atol=tol, rtol=tol
+        )

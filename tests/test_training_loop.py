@@ -1,8 +1,10 @@
 """Integration: Trainer over the real threaded DELI pipeline — loss falls,
 checkpoint/restore resumes exactly, elastic re-partitioning works."""
+import dataclasses
 import tempfile
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -22,7 +24,7 @@ CFG = ArchConfig(
 )
 
 
-def _trainer(ckpt_dir=None, every=5, n_samples=512):
+def _trainer(ckpt_dir=None, every=5, n_samples=512, cfg=CFG):
     # ISSUE 4 satellite: the trainer's pipeline comes from the declarative
     # LM spec (make_lm_pipeline folded into DataPlaneSpec).
     spec = make_lm_spec(
@@ -32,7 +34,7 @@ def _trainer(ckpt_dir=None, every=5, n_samples=512):
     cluster = spec.build_runtime(clock=RealClock())
     loader, service = cluster.loaders[0], cluster.services[0]
     t = Trainer(
-        CFG, loader,
+        cfg, loader,
         TrainerConfig(seq_len=SEQ, batch_size=BATCH, checkpoint_dir=ckpt_dir,
                       checkpoint_every=every, log_every=1000),
         decode_fn=decode_tokens,
@@ -73,6 +75,29 @@ def test_checkpoint_restore_resumes_exactly():
     with svc2:
         t2.train(3)
     assert t2.step == 13
+
+
+def test_bf16_checkpoint_resumes_exactly():
+    """bf16 leaves survive np.savez (stored as raw '|V2' records) bit for
+    bit, and the resumed run's next step equals the uninterrupted one's."""
+    cfg = dataclasses.replace(CFG, dtype="bfloat16")
+    d = tempfile.mkdtemp()
+    t1, svc1 = _trainer(ckpt_dir=d, every=5, cfg=cfg)
+    with svc1:
+        t1.train(10)
+    saved = [np.asarray(x) for x in jax.tree.leaves((t1.params, t1.opt_state))]
+    assert saved[0].dtype == jnp.bfloat16
+    with svc1:
+        t1.train(1)
+
+    t2, svc2 = _trainer(ckpt_dir=d, every=5, cfg=cfg)
+    assert t2.try_restore() and t2.step == 10
+    restored = [np.asarray(x) for x in jax.tree.leaves((t2.params, t2.opt_state))]
+    for a, b in zip(saved, restored):
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+    with svc2:
+        t2.train(1)
+    assert t2.metrics[-1].loss == t1.metrics[-1].loss
 
 
 def test_checkpoint_atomic_and_gc():
