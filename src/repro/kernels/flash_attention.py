@@ -168,6 +168,7 @@ def flash_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="flash_attention",  # the HLO instruction's name in a device trace
     )(qg, kg, vg)
     out = out.reshape(B, H, Sq_p, hd).transpose(0, 2, 1, 3)
     return out[:, :Sq]

@@ -158,5 +158,8 @@ def ssd_scan(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        # Names the HLO instruction (ssd_scan.<n>) by which a device trace
+        # finds the kernel, whatever function calls it.
+        name="ssd_scan",
     )(xg, dtg, A2, Bg, Cg, D2)
     return y.transpose(0, 2, 1, 3), st

@@ -121,11 +121,13 @@ def main():
     )
     metrics = trainer.metrics
     wait = sum(m.data_wait_s for m in metrics)
+    prepare = sum(m.prepare_s for m in metrics)
+    dispatch = sum(m.dispatch_s for m in metrics)
     comp = sum(m.compute_s for m in metrics)
     print(
         f"done: step {trainer.step} loss {metrics[-1].loss:.4f} | "
-        f"data-wait {wait:.2f}s / compute {comp:.1f}s "
-        f"({wait/(wait+comp):.1%} wait fraction)"
+        f"data-wait {wait:.2f}s, prepare {prepare:.2f}s, dispatch {dispatch:.2f}s, "
+        f"device step {comp:.1f}s"
     )
 
 

@@ -7,12 +7,21 @@ loader's miss/wait metrics decide whether the input pipeline (not the mesh)
 is the bottleneck, exactly the measurement DELI §V makes — but per training
 step instead of per epoch, because a pod-scale job wants to see data-wait
 within the step budget, not after an epoch is lost.
+
+Each step is a profiler step span ``train.step`` (its ``step_num`` is the
+step), and each host phase of it a span inside: ``train.next_batch`` (the
+request to the loader), ``train.prepare`` (decode, stack, the copy to the
+device), ``train.dispatch`` (the call of the jitted step), ``train.block``
+(waiting for its result) and ``train.read_loss``.  They land in the same
+trace as the device's operations, on its clock, whenever a profiler trace
+runs, and cost a few microseconds each when none does.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, Iterator, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,11 +43,18 @@ class StepMetrics:
     compute_s: float
     hits: int
     misses: int
+    prepare_s: float  # the train.prepare span: decode, stack, copy to the device
+    dispatch_s: float  # the train.dispatch span: the jitted step's call until it returns
 
-    @property
-    def wait_fraction(self) -> float:
-        tot = self.data_wait_s + self.compute_s
-        return self.data_wait_s / tot if tot else 0.0
+
+@contextlib.contextmanager
+def _phase(name: str, seconds: Dict[str, float]) -> Iterator[None]:
+    """A profiler span ``name`` around the block, whose host duration goes
+    to ``seconds[name]``: a span and its counter cannot drift apart."""
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(name):
+        yield
+    seconds[name] = time.perf_counter() - t0
 
 
 @dataclasses.dataclass
@@ -125,33 +141,53 @@ class Trainer:
         epoch = self.loader.state_dict()["epoch"]
         while self.step < target and epoch < epochs:
             self.loader.set_epoch(epoch)
-            for batch in self.loader:
-                dev_batch = self._to_device_batch(batch)
-                t0 = time.monotonic()
-                loss, self.params, self.opt_state = jax.block_until_ready(
-                    self._step_fn(self.params, self.opt_state, dev_batch)
-                )  # compute_s spans the whole device step
-                loss = float(loss)
-                compute_s = time.monotonic() - t0
-                self.step += 1
-                m = StepMetrics(
-                    self.step, loss, batch.data_wait_s, compute_s,
-                    batch.hits, batch.misses,
-                )
-                self.metrics.append(m)
-                if self.step % self.tcfg.log_every == 0:
-                    print(
-                        f"step {self.step} loss {loss:.4f} "
-                        f"wait {m.data_wait_s*1e3:.1f}ms ({m.wait_fraction:.0%}) "
-                        f"miss {batch.misses}/{batch.hits + batch.misses}"
-                    )
-                self._maybe_checkpoint()
-                if self.step >= target:
-                    break
+            batches = iter(self.loader)
+            while self._train_step(batches) and self.step < target:
+                pass
             epoch += 1
         if self._ckpt:
             self._ckpt.wait()
         return self.metrics
+
+    def _train_step(self, batches: Iterator[Batch]) -> bool:
+        """One step from the loader's next batch; False where there is none.
+
+        ``compute_s`` runs from the call of the jitted step to the end of
+        the loss read: the device step as the host sees it.  A request that
+        finds no batch still lies in a ``train.step`` span, since the span
+        opens before the request and a profiler span cannot be withdrawn.
+        """
+        seconds: Dict[str, float] = {}
+        with jax.profiler.StepTraceAnnotation("train.step", step_num=self.step + 1):
+            with _phase("train.next_batch", seconds):
+                batch = next(batches, None)
+            if batch is None:
+                return False
+            with _phase("train.prepare", seconds):
+                dev_batch = self._to_device_batch(batch)
+            t0 = time.perf_counter()
+            with _phase("train.dispatch", seconds):
+                out = self._step_fn(self.params, self.opt_state, dev_batch)
+            with _phase("train.block", seconds):
+                loss, self.params, self.opt_state = jax.block_until_ready(out)
+            with _phase("train.read_loss", seconds):
+                loss = float(loss)
+            compute_s = time.perf_counter() - t0
+            self.step += 1
+            m = StepMetrics(
+                self.step, loss, batch.data_wait_s, compute_s, batch.hits, batch.misses,
+                prepare_s=seconds["train.prepare"], dispatch_s=seconds["train.dispatch"],
+            )
+            self.metrics.append(m)
+            if self.step % self.tcfg.log_every == 0:
+                print(
+                    f"step {self.step} loss {loss:.4f} "
+                    f"wait {m.data_wait_s*1e3:.1f}ms prepare {m.prepare_s*1e3:.1f}ms "
+                    f"dispatch {m.dispatch_s*1e3:.1f}ms step {m.compute_s*1e3:.1f}ms "
+                    f"miss {batch.misses}/{batch.hits + batch.misses}"
+                )
+            self._maybe_checkpoint()
+        return True
 
     # -- paper metrics ------------------------------------------------------------
     def epoch_wait_summary(self) -> Dict[int, float]:

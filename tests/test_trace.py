@@ -81,7 +81,7 @@ def _traced_sim_run(spec, epochs=2):
 # ---------------------------------------------------------------------------
 # 1. Event-level parity, sim vs runtime AND scalar vs vector.
 # ---------------------------------------------------------------------------
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(
     name=st.sampled_from(CONDITION_NAMES),
     sync=st.sampled_from(["epoch", "batch"]),
@@ -95,7 +95,7 @@ def test_trace_parity_matrix(name, sync, granularity, engine, seed):
     assert_trace_parity(_spec(name, sync, granularity, engine, seed), epochs=2)
 
 
-@settings(max_examples=12)
+@settings(max_examples=12, deadline=None)
 @given(
     name=st.sampled_from(CONDITION_NAMES),
     sync=st.sampled_from(["epoch", "batch"]),
@@ -143,7 +143,7 @@ def test_trace_parity_report_diverged_renders():
 # ---------------------------------------------------------------------------
 # 2. Ledger reconciliation: sum-of-ledger == counters, exactly.
 # ---------------------------------------------------------------------------
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(
     name=st.sampled_from(CONDITION_NAMES),
     sync=st.sampled_from(["epoch", "batch"]),
@@ -178,7 +178,7 @@ def test_ledger_lines_attribute_every_charge():
 # ---------------------------------------------------------------------------
 # 3. Observer purity: tracing-off == tracing-on, byte for byte.
 # ---------------------------------------------------------------------------
-@settings(max_examples=12)
+@settings(max_examples=12, deadline=None)
 @given(
     name=st.sampled_from(CONDITION_NAMES),
     engine=st.sampled_from(["scalar", "vector"]),

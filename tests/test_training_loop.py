@@ -123,3 +123,50 @@ def test_elastic_repartition_halves_partition():
     with svc:
         t.train(3)  # keeps training on the new partition
     assert t.step == 6
+
+
+PHASES = ("train.next_batch", "train.prepare", "train.dispatch", "train.block", "train.read_loss")
+
+
+def test_each_step_and_its_phases_are_profiler_spans(tmp_path):
+    """Under a profiler trace every step is one ``train.step`` span with its
+    ``step_num``, holding one span of each host phase; the phase counters
+    agree with what they time."""
+    from jax.profiler import ProfileData
+
+    t, svc = _trainer()
+    with svc:
+        t.train(1)  # compiles outside the trace
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            t.train(3)
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    spans = [
+        (e.name, e.start_ns, e.end_ns, dict(e.stats))
+        for plane in ProfileData.from_file(str(path)).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+        if e.name.startswith("train.")
+    ]
+    steps = sorted((s for s in spans if s[0] == "train.step"), key=lambda s: s[1])
+    assert [s[3].get("step_num") for s in steps] == [2, 3, 4]
+    for _, a, b, _ in steps:
+        inside = sorted((s for s in spans if s[0] in PHASES and a <= s[1] and s[2] <= b),
+                        key=lambda s: s[1])
+        assert [s[0] for s in inside] == list(PHASES)
+    assert sum(s[0] in PHASES for s in spans) == 3 * len(PHASES)
+    for m in t.metrics:
+        assert m.prepare_s >= 0 and 0 <= m.dispatch_s <= m.compute_s
+
+
+def test_epoch_end_stops_the_call_as_before():
+    """The loader's epoch end ends a step loop without a step; the call
+    goes on into the next epoch until ``epochs`` is reached."""
+    t, svc = _trainer(n_samples=64)  # 16 batches an epoch
+    with svc:
+        metrics = t.train(100, epochs=2)
+    assert len(metrics) == 32 and t.step == 32
+    assert [m.step for m in metrics] == list(range(1, 33))
