@@ -1,103 +1,174 @@
-"""Pallas TPU kernel for the Mamba-2 SSD chunked scan.
+"""Pallas TPU kernel for the Mamba-2 SSD chunked scan (forward).
 
-Grid: (batch, heads, S/chunk) with the chunk dimension innermost and
-sequential ("arbitrary") — the (P, N) SSD state lives in VMEM scratch and
-is carried across chunk steps, exactly the inter-chunk recurrence of the
-SSD algorithm.  Per step the kernel does four MXU matmuls per head:
+Grid: (batch, head blocks, S/chunk), chunk innermost and sequential
+("arbitrary"): the SSD state of the block's heads lives in VMEM scratch and
+is carried across chunk steps, the inter-chunk recurrence of the SSD
+algorithm.  A head block is ``hb`` consecutive heads of one B/C group
+(``head_block`` picks ``hb``), so one grid step covers every head that
+shares the step's B and C.
 
-    cb   = C  B^T                (Q,N)x(N,Q)   intra-chunk scores
-    y    = (cb * L * dt) x       (Q,Q)x(Q,P)   intra-chunk output
-    y   += (C S^T) * exp(a_cum)  (Q,N)x(N,P)   inter-chunk output
-    S'   = exp(a_tot) S + x^T(w*B)  (P,Q)x(Q,N) state update
+The kernel reads the model's own layout: x and y as (B, S, H*P) lane
+blocks of hb*P, B and C as (B, S, G*N) lane blocks of N, the final state as
+(B, H*P, N).  Only dt is transposed by the wrapper, to (B, H, S), a (hb, Q)
+block whose rows are the heads (4 bytes a token a head).
 
-VMEM working set per step: x (Q,P) + B,C (Q,N) + state (P,N) f32 + the
-(Q,Q) decay matrix — with Q=128, P=64, N=128 that is ~260 KB, comfortably
-inside the ~16 MB VMEM budget with double buffering.
+Once per step, for the whole block:
 
-Heads are gridded individually (block_h == 1): every matmul above is then a
-clean 2-D MXU op; B/C index maps select the head's group (G | H), so grouped
-B/C are never materialized per head in HBM.
+    cb    = C B^T                        (Q,N)x(N,Q)  intra-chunk scores
+    a_cum = prefix sum of dt*A over Q    (hb,Q)x(Q,Q) f32, precision HIGHEST
+    and the column forms of a_cum and dt, one (hb,Q) transpose each.
+
+Per head (Q = chunk, P = head dim, N = state):
+
+    L     = exp(a_cum_i - a_cum_j), i >= j
+    y     = (cb * L * dt) x              (Q,Q)x(Q,P)
+    y    += (C S^T) * exp(a_cum)         (Q,N)x(N,P)
+    S'    = exp(a_tot) S + x^T (w*B)     (P,Q)x(Q,N), w = exp(a_tot - a_cum) dt
+
+Heads are handled in windows of 128 lanes (two heads at P=64): each
+window's matmuls take the whole aligned window and each head keeps its own
+lanes by a select, so no slice cuts a lane tile.  The state is kept
+transposed, (N, hb*P), so that C S^T is a plain matmul over a window's
+lanes; it is transposed back once, when the last chunk writes it out.
+Everything but the bf16 inputs is f32.  The f32 matmuls keep Mosaic's
+default precision; only the prefix sum, which the decays of a whole chunk
+ride on, asks for HIGHEST.
+
+VMEM working set per step: x and y blocks (Q, hb*P), B and C (Q, N), dt
+(hb, Q), D (1, hb*P) and the final-state block (hb*P, N) f32, all double
+buffered, plus the (N, hb*P) f32 state scratch.  ``head_block`` takes the
+largest divisor of H/G whose working set fits ``VMEM_BUDGET`` and whose
+blocks tile the TPU's (8, 128) layout.  At mamba2-130m widths (H 24, P 64,
+N 128, chunk 128) the whole group fits: hb = 24, 4.1 MB, 128 grid steps at
+batch 8 and sequence 2048.  At Jamba-1.5 widths (H 256, chunk 256) hb = 32.
+Widths whose head blocks cannot tile (only the tests' tiny ones) take the
+largest block that fits; interpret mode runs them, Mosaic would refuse them.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+#: Bytes of VMEM a grid step's blocks (double buffered) and state scratch
+#: may take: half the 16 MB scoped default, the rest left to the kernel's
+#: (Q, Q) and (Q, 128) temporaries.
+VMEM_BUDGET = 8 * 2**20
+
+_LANES = 128
+
+
+def block_bytes(hb: int, P: int, N: int, chunk: int, itemsize: int) -> int:
+    """VMEM one grid step holds for a block of ``hb`` heads."""
+    lanes = hb * P
+    blocks = (
+        2 * chunk * lanes * itemsize  # x, y
+        + 2 * chunk * N * itemsize  # B, C
+        + hb * chunk * 4  # dt
+        + lanes * 4  # D
+        + lanes * N * 4  # final state
+    )
+    return 2 * blocks + lanes * N * 4
+
+
+def head_block(H: int, G: int, P: int, N: int, chunk: int, itemsize: int) -> int:
+    """Heads per grid step: a divisor of H/G, the largest that fits
+    ``VMEM_BUDGET`` and tiles (rows of dt a multiple of 8, lanes of x a
+    multiple of 128, or the whole head axis)."""
+    rep = H // G
+    fit = [d for d in range(1, rep + 1)
+           if rep % d == 0 and block_bytes(d, P, N, chunk, itemsize) <= VMEM_BUDGET]
+    tiled = [d for d in fit if d == H or (d % 8 == 0 and d * P % _LANES == 0)]
+    return max(tiled or fit or [1])
+
+
+def _dot(a, b, contract=((1,), (0,)), **kw):
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())), preferred_element_type=jnp.float32, **kw
+    )
+
 
 def _ssd_kernel(
-    x_ref,  # (Q, P)   this (b, h, chunk)'s inputs
-    dt_ref,  # (Q, 1)
-    A_ref,  # (1, H)   SMEM: per-head decay scalars, whole array
-    B_ref,  # (Q, N)
-    C_ref,  # (Q, N)
-    D_ref,  # (1, H)   SMEM: per-head skip scalars, whole array
-    y_ref,  # (Q, P)   output
-    st_ref,  # (P, N)  final-state output (written on last chunk)
-    state,  # VMEM scratch (P, N) f32: the carried SSD state
+    x_ref,  # (1, Q, hb*P) this block's inputs
+    dt_ref,  # (1, hb, Q)
+    A_ref,  # (hb, 1)
+    B_ref,  # (1, Q, N)   the block's group
+    C_ref,  # (1, Q, N)
+    D_ref,  # (1, hb*P)   per-head skip, repeated over each head's lanes
+    y_ref,  # (1, Q, hb*P) output
+    st_ref,  # (1, hb*P, N) final-state output (written on the last chunk)
+    state,  # VMEM scratch (N, hb*P) f32: the carried state, transposed
     *,
     chunk: int,
+    heads: int,
+    head_dim: int,
     n_chunks: int,
 ):
-    h = pl.program_id(1)
+    Q, P = chunk, head_dim
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def init():
         state[...] = jnp.zeros_like(state)
 
-    x = x_ref[0, 0].astype(jnp.float32)  # (Q, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)  # (Q, 1)
-    A = A_ref[0, h]
-    Bm = B_ref[0, 0].astype(jnp.float32)  # (Q, N)
-    Cm = C_ref[0, 0].astype(jnp.float32)
-
-    # Mosaic lowers neither cumsum nor a (Q, 1) -> (1, Q) reshape, so the
-    # prefix sum is a masked lane reduction and row forms come from
-    # transposing a (Q, Q) broadcast.
-    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    f32 = jnp.float32
+    ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
     causal = ii >= jj
 
-    def rows(col):  # (Q, 1) -> (Q, Q) with [i, j] = col[j]
-        return jnp.broadcast_to(col, (chunk, chunk)).T
+    Bm, Cm = B_ref[0], C_ref[0]  # (Q, N)
+    # bf16 products are exact in f32, so C.B^T from the inputs as they come
+    # is the f32 product.
+    cb = _dot(Cm, Bm, ((1,), (1,)))  # (Qi, Qj)
+    Cm, Bm = Cm.astype(f32), Bm.astype(f32)
 
-    a = dt * A  # (Q, 1) log-decay per step
-    a_cum = jnp.sum(jnp.where(causal, rows(a), 0.0), axis=1, keepdims=True)  # (Q, 1)
-    a_tot = jnp.sum(a, axis=0, keepdims=True)  # (1, 1)
+    # The decay chain of every head at once, heads on rows.  Mosaic lowers
+    # no cumsum: the prefix sum is a matmul with the causal mask, in f32.
+    dt_r = dt_ref[0].astype(f32)  # (hb, Q)
+    a_cum_r = _dot(
+        dt_r * A_ref[...], causal.astype(f32), ((1,), (1,)),
+        precision=jax.lax.Precision.HIGHEST,
+    )  # (hb, Qi): sum over j <= i of dt_j A
+    a_cum_c = a_cum_r.T  # (Q, hb)
+    dt_c = dt_r.T
+    a_tot = a_cum_c[Q - 1:, :]  # (1, hb)
+    decay_c = jnp.exp(a_cum_c)  # inter-chunk output scale
+    w_c = jnp.exp(a_tot - a_cum_c) * dt_c  # state-update weights
+    decay_tot = jnp.exp(a_tot)
 
-    # intra-chunk: L[i,j] = exp(a_i - a_j) for i >= j
-    seg = a_cum - rows(a_cum)  # (Qi, Qj)
-    L = jnp.exp(jnp.where(causal, seg, -jnp.inf))  # no inf above the diagonal
-    cb = jax.lax.dot_general(
-        Cm, Bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (Qi, Qj)
-    M = cb * L * rows(dt)
-    y = jax.lax.dot_general(
-        M, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (Q, P)
-
-    # inter-chunk: y_i += exp(a_cum_i) * C_i . S^T
-    cs = jax.lax.dot_general(
-        Cm, state[...], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (Q, P)
-    y = y + cs * jnp.exp(a_cum)
-
-    # state update: S' = exp(a_tot) S + x^T (w * B), w = exp(a_tot - a_cum) dt
-    w = jnp.exp(a_tot - a_cum) * dt  # (Q, 1)
-    su = jax.lax.dot_general(
-        x, Bm * w, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (P, N)
-    state[...] = state[...] * jnp.exp(a_tot) + su
-
-    y_ref[0, 0] = (y + x * D_ref[0, h]).astype(y_ref.dtype)
+    # Windows of whole heads, 128 lanes where P divides 128.
+    per_win = math.gcd(heads, max(1, _LANES // P))
+    W = per_win * P
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+    for w0 in range(0, heads, per_win):
+        lanes = pl.ds(w0 * P, W)
+        x = x_ref[0, :, lanes].astype(f32)  # (Q, W)
+        s = state[:, lanes]  # (N, W)
+        cs = _dot(Cm, s)  # (Q, W): C S^T of each head in its lanes
+        for h in range(w0, w0 + per_win):
+            col = a_cum_c[:, h:h + 1]
+            L = jnp.exp(jnp.where(causal, col - a_cum_r[h:h + 1, :], -jnp.inf))
+            M = cb * L * dt_r[h:h + 1, :]
+            y_h = _dot(M, x) + cs * decay_c[:, h:h + 1]
+            xw_h = x * w_c[:, h:h + 1]
+            dec_h = decay_tot[:, h:h + 1]
+            if h == w0:
+                y, xw, dec = y_h, xw_h, dec_h
+            else:
+                mine = (lane >= (h - w0) * P) & (lane < (h - w0 + 1) * P)
+                y = jnp.where(mine, y_h, y)
+                xw = jnp.where(mine, xw_h, xw)
+                dec = jnp.where(mine, dec_h, dec)
+        state[:, lanes] = s * dec + _dot(Bm, xw, ((0,), (0,)))  # (N, W)
+        y_ref[0, :, lanes] = (y + x * D_ref[:, lanes]).astype(y_ref.dtype)
 
     @pl.when(ci == n_chunks - 1)
     def emit_state():
-        st_ref[0, 0] = state[...].astype(st_ref.dtype)
+        st_ref[0] = state[...].T.astype(st_ref.dtype)
 
 
 def ssd_scan(
@@ -117,43 +188,43 @@ def ssd_scan(
     """
     B, S, H, P = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
-    rep = H // G
     assert S % chunk == 0, (S, chunk)
     nc = S // chunk
+    hb = head_block(H, G, P, N, chunk, x.dtype.itemsize)
+    per_group = H // G // hb
 
-    xg = x.transpose(0, 2, 1, 3)  # (B, H, S, P)
-    dtg = dt.transpose(0, 2, 1)[..., None]  # (B, H, S, 1)
-    Bg = Bm.transpose(0, 2, 1, 3)  # (B, G, S, N)
-    Cg = Cm.transpose(0, 2, 1, 3)
-    # Per-head scalars go to SMEM whole: a (1, 1) VMEM block of an (H, 1)
-    # array breaks the TPU's (8, 128) tiling rule, and SMEM is where scalars
-    # that steer vector math belong.
-    A2 = A.astype(jnp.float32).reshape(1, H)
-    D2 = D.astype(jnp.float32).reshape(1, H)
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    x2 = x.reshape(B, S, H * P)
+    dtT = dt.transpose(0, 2, 1)  # (B, H, S): a block's heads on rows
+    A2 = A.astype(jnp.float32).reshape(H, 1)
+    D2 = jnp.repeat(D.astype(jnp.float32), P).reshape(1, H * P)
+    B2 = Bm.reshape(B, S, G * N)
+    C2 = Cm.reshape(B, S, G * N)
 
-    grid = (B, H, nc)
-    kernel = functools.partial(_ssd_kernel, chunk=chunk, n_chunks=nc)
+    kernel = functools.partial(
+        _ssd_kernel, chunk=chunk, heads=hb, head_dim=P, n_chunks=nc
+    )
+    group = pl.BlockSpec((1, chunk, N), lambda b, i, c: (b, c, i // per_group))
+    lanes = pl.BlockSpec((1, chunk, hb * P), lambda b, i, c: (b, c, i))
     y, st = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B, H // hb, nc),
         in_specs=[
-            pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, chunk, 1), lambda b, h, c: (b, h, c, 0)),
-            smem,
-            pl.BlockSpec((1, 1, chunk, N), lambda b, h, c, rep=rep: (b, h // rep, c, 0)),
-            pl.BlockSpec((1, 1, chunk, N), lambda b, h, c, rep=rep: (b, h // rep, c, 0)),
-            smem,
+            lanes,
+            pl.BlockSpec((1, hb, chunk), lambda b, i, c: (b, i, c)),
+            pl.BlockSpec((hb, 1), lambda b, i, c: (i, 0)),
+            group,
+            group,
+            pl.BlockSpec((1, hb * P), lambda b, i, c: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
+            lanes,
+            pl.BlockSpec((1, hb * P, N), lambda b, i, c: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, S, P), x.dtype),
-            jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, S, H * P), x.dtype),
+            jax.ShapeDtypeStruct((B, H * P, N), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, hb * P), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
@@ -161,5 +232,5 @@ def ssd_scan(
         # Names the HLO instruction (ssd_scan.<n>) by which a device trace
         # finds the kernel, whatever function calls it.
         name="ssd_scan",
-    )(xg, dtg, A2, Bg, Cg, D2)
-    return y.transpose(0, 2, 1, 3), st
+    )(x2, dtT, A2, B2, C2, D2)
+    return y.reshape(B, S, H, P), st.reshape(B, H, P, N)

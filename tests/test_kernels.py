@@ -97,6 +97,8 @@ def _ssd_inputs(key, B, S, H, P, G, N, dtype):
         (1, 64, 2, 16, 1, 16, 16),   # minimal
         (2, 128, 4, 32, 2, 16, 32),  # grouped B/C
         (1, 96, 3, 16, 1, 32, 32),   # odd head count, 3 chunks
+        (1, 256, 4, 64, 1, 128, 128),  # production lanes, several heads a block
+        (2, 128, 8, 32, 2, 16, 32),  # two groups, four heads each
     ],
 )
 def test_ssd_scan(B, S, H, P, G, N, chunk, dtype):
@@ -110,6 +112,22 @@ def test_ssd_scan(B, S, H, P, G, N, chunk, dtype):
     np.testing.assert_allclose(
         np.asarray(st, np.float32), np.asarray(st_ref, np.float32), atol=tol, rtol=tol
     )
+
+
+def test_ssd_head_block_divides_the_group_and_fits():
+    from repro.kernels.ssd import VMEM_BUDGET, block_bytes, head_block
+
+    # mamba2-130m: the whole group of 24 heads in one grid step.
+    assert head_block(24, 1, 64, 128, 128, 2) == 24
+    # Jamba-1.5: 256 heads do not fit; the block splits the group.
+    hb = head_block(256, 1, 64, 128, 256, 2)
+    assert 256 % hb == 0 and hb < 256
+    assert block_bytes(hb, 64, 128, 256, 2) <= VMEM_BUDGET
+    assert hb % 8 == 0 and hb * 64 % 128 == 0
+    for H, G, P, N, chunk in [(2, 1, 16, 16, 16), (4, 2, 32, 16, 32), (3, 1, 16, 32, 32),
+                              (8, 2, 32, 16, 32), (128, 8, 64, 128, 256), (48, 1, 128, 256, 256)]:
+        hb = head_block(H, G, P, N, chunk, 2)
+        assert (H // G) % hb == 0, (H, G, hb)
 
 
 def test_ssd_kernel_matches_model_chunked_path():

@@ -9,6 +9,7 @@ running this file loads the TPU library.  The persistent compilation cache
 is off here: entries compiled for a described chip cannot be read back.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -55,10 +56,9 @@ def on_chip(topo):
     return struct
 
 
-def test_ssd_kernel_compiles_at_mamba2_130m_widths(on_chip):
+def _ssd_hlo(on_chip, B, S, H, P, N, chunk):
     from repro.kernels.ssd import ssd_scan
 
-    B, S, H, P, N = 8, 2048, 24, 64, 128
     args = (
         on_chip((B, S, H, P), jnp.bfloat16),
         on_chip((B, S, H), jnp.float32),
@@ -67,12 +67,23 @@ def test_ssd_kernel_compiles_at_mamba2_130m_widths(on_chip):
         on_chip((B, S, 1, N), jnp.bfloat16),
         on_chip((H,), jnp.float32),
     )
-    step = jax.jit(lambda *a: ssd_scan(*a, chunk=128, interpret=False))
-    hlo = step.lower(*args).compile().as_text()
+    step = jax.jit(lambda *a: ssd_scan(*a, chunk=chunk, interpret=False))
+    return step.lower(*args).compile().as_text()
+
+
+def test_ssd_kernel_compiles_at_mamba2_130m_widths(on_chip):
+    hlo = _ssd_hlo(on_chip, B=8, S=2048, H=24, P=64, N=128, chunk=128)
     assert "tpu_custom_call" in hlo
     # The kernel's own name, not the caller's, names its HLO instruction:
-    # the device trace (and the benchmark's ssd_fwd_roofline) find it by it.
-    assert "%ssd_scan." in hlo
+    # the device trace (and the benchmark's ssd_fwd_roofline) find it by it,
+    # one call per forward.
+    assert len(re.findall(r"%ssd_scan(?:\.\d+)? = .*custom-call\(", hlo)) == 1
+
+
+def test_ssd_kernel_compiles_at_jamba_widths(on_chip):
+    # 256 heads of one group: the head block splits the group to fit VMEM.
+    hlo = _ssd_hlo(on_chip, B=1, S=2048, H=256, P=64, N=128, chunk=256)
+    assert len(re.findall(r"%ssd_scan(?:\.\d+)? = .*custom-call\(", hlo)) == 1
 
 
 def test_flash_attention_compiles_at_internlm2_20b_widths(on_chip):
