@@ -3,6 +3,8 @@
 
     JAX_PLATFORMS=cpu python bench/aot_check.py [--batch N] [config ...]
 
+Without names it compiles every configuration of ``BENCHMARK.json``.
+
 The step is the trainer's own, jitted as ``Trainer`` jits it (no donated
 arguments), over the benchmark's weight tree, fp32 moments and a
 ``(batch, seq_len)`` token batch on one chip of a ``v5e:2x2`` topology that
@@ -25,7 +27,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("configs", nargs="*", default=["mamba2-130m"])
+    ap.add_argument("configs", nargs="*", help="configuration names (default: every one)")
     ap.add_argument("--batch", type=int, help="batch to compile (default: the file's)")
     args = ap.parse_args()
 
@@ -39,9 +41,10 @@ def main() -> None:
 
     from bench.harness import load_json, load_module
 
+    names = args.configs or [c["name"] for c in load_json(ROOT / "BENCHMARK.json")["configs"]]
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
-    for name in args.configs:
+    for name in names:
         conf = load_json(ROOT / "bench" / "configs" / f"{name}.json")
         model = load_module(ROOT / "bench" / "models" / f"{conf['model_type']}.py")
         tr = conf["train"]

@@ -23,6 +23,9 @@ from bench.reference import rms_norm, mean_cross_entropy
 #: Leaves the program keeps in float32 whatever the configured dtype.
 F32_LEAVES = ("A_log", "D", "dt_bias")
 
+#: Keys that cut a configuration to a size the CPU trains in seconds (tests only).
+SMOKE = dict(d_model=64, n_layer=2, vocab_size=500, d_state=16, headdim=16, chunk_size=16)
+
 
 def dims(conf: Dict) -> Dict[str, int]:
     d = conf["d_model"]

@@ -1,8 +1,9 @@
 """Cells of ``BENCHMARK.json`` cut to a size the CPU runs in seconds.
 
-Only the tests use these: widths, depth, vocabulary, sequence and dataset
-are shrunk, and the bucket answers in a tenth of a millisecond.  The
-harness, the program's path and the check are the cell's own.
+Only the tests use these: widths, depth and vocabulary are cut by the
+model file's ``SMOKE``, sequence and dataset here, and the bucket answers in
+a tenth of a millisecond.  The harness, the program's path and the check are
+the cell's own.
 """
 import copy
 import time
@@ -11,17 +12,13 @@ import jax
 
 from bench import harness
 
-SMOKE_MODEL = {
-    "mamba2": dict(d_model=64, n_layer=2, vocab_size=500, d_state=16, headdim=16, chunk_size=16),
-}
-
 _load_cell = harness.load_cell  # tests may patch harness.load_cell with smoke_cell
 
 
 def smoke_cell(name: str) -> harness.Cell:
     cell = _load_cell(name)
     conf, traffic = copy.deepcopy(cell.conf), copy.deepcopy(cell.traffic)
-    conf.update(SMOKE_MODEL[conf["model_type"]])
+    conf.update(cell.model.SMOKE)
     conf["train"].update(seq_len=64, batch=4)
     traffic.update(n_objects=2048)
     traffic["bucket"].update(request_latency_s=1e-4, listing_latency_s=1e-4)

@@ -2,13 +2,13 @@
 import copy
 
 from bench import harness
-from bench.tests.smoke import SMOKE_MODEL
 
 
 def _conf(config: str):
     conf = copy.deepcopy(harness.load_json(harness.BENCH / "configs" / f"{config}.json"))
-    conf.update(SMOKE_MODEL[conf["model_type"]])
-    return conf, harness.load_module(harness.BENCH / "models" / f"{conf['model_type']}.py")
+    model = harness.load_module(harness.BENCH / "models" / f"{conf['model_type']}.py")
+    conf.update(model.SMOKE)
+    return conf, model
 
 
 def test_mamba2_counts():

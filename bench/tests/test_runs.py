@@ -15,7 +15,23 @@ from bench.tests.smoke import smoke_cell, smoke_run
 from bench.tests.test_files import CHECKS
 
 KEYS = ["correct", "attempted", "failed", "metrics", "device", "checks"]
-WORKLOADS = [c["name"] for c in harness.load_json(harness.ROOT / "BENCHMARK.json")["workloads"]]
+BM = harness.load_json(harness.ROOT / "BENCHMARK.json")
+WORKLOADS = [c["name"] for c in BM["workloads"]]
+
+
+def _first_cell_of_each_model_type():
+    """``{model_type: cell}``, the first cell of ``BENCHMARK.json`` whose
+    configuration names each model type.  Cells of one model type run the
+    same code at smoke size, so one of them stands for all."""
+    files = {c["name"]: c["file"] for c in BM["configs"]}
+    cells = {}
+    for cell in BM["workloads"]:
+        conf = harness.load_json(harness.ROOT / files[cell["config"]])
+        cells.setdefault(conf["model_type"], cell["name"])
+    return cells
+
+
+MODEL_CELLS = _first_cell_of_each_model_type()
 
 
 @pytest.mark.parametrize("workload", WORKLOADS)
@@ -47,11 +63,12 @@ def test_without_a_tpu_it_exits_nonzero_and_prints_no_result():
     assert not proc.stdout.strip()
 
 
-def test_control_fails_the_check():
+@pytest.mark.parametrize("workload", list(MODEL_CELLS.values()), ids=list(MODEL_CELLS))
+def test_control_fails_the_check(workload):
     """The control, the reference computed with float8 matmuls and put in
     the program's place, comes out as not correct against the cell's limits
-    (one cell: both share the configuration and its limits)."""
-    sound_run = smoke_run("mamba2-130m.bucket-prefetch")
+    (one cell of each model type)."""
+    sound_run = smoke_run(workload)
     ref = harness.reference_readings(sound_run, "fp32")
     sound = harness.checks(sound_run, ref)
     control = reference.compare(harness.reference_readings(sound_run, "fp8"), ref)
@@ -61,9 +78,11 @@ def test_control_fails_the_check():
 
 
 @pytest.mark.parametrize("fault", sorted(faults.FAULTS))
-def test_each_fault_fails_the_check(fault):
-    """With the timed path broken underneath, the check comes out false."""
+@pytest.mark.parametrize("workload", list(MODEL_CELLS.values()), ids=list(MODEL_CELLS))
+def test_each_fault_fails_the_check(workload, fault):
+    """With the timed path broken underneath, the check comes out false
+    (one cell of each model type)."""
     with faults.FAULTS[fault]():
-        broken = smoke_run("mamba2-130m.bucket-prefetch")
+        broken = smoke_run(workload)
     checked = harness.checks(broken)
     assert not harness.passed(checked), checked
