@@ -164,7 +164,8 @@ def phase_kernel_vs_reference(trainer) -> None:
 def _host_leaves(tree):
     import jax
 
-    return [np.asarray(x) for x in jax.tree.leaves(tree)]
+    # Copies: the trainer's next step takes the device buffers over.
+    return [np.array(x, copy=True) for x in jax.tree.leaves(tree)]
 
 
 def _bitwise_equal(a, b) -> bool:
@@ -223,7 +224,7 @@ def phase_data_parallel(chips: int) -> None:
 
     from repro.distributed.sharding import ShardingRules, input_shardings, param_shardings
     from repro.launch.mesh import make_smoke_mesh
-    from repro.launch.steps import step_for_cell
+    from repro.launch.steps import make_train_step, step_for_cell
     from repro.models import model as M
     from repro.models.config import ShapeConfig
 
@@ -240,10 +241,13 @@ def phase_data_parallel(chips: int) -> None:
                 break
     params0, opt0 = ref.params, ref.opt_state
 
+    # The trainer's own step takes its arguments over; this one keeps
+    # params0 and opt0 for the sharded run.
+    one_step = jax.jit(make_train_step(cfg, ref.settings))
     one_losses = []
     params, opt = params0, opt0
     for b in batches:
-        loss, params, opt = jax.block_until_ready(ref._step_fn(params, opt, b))
+        (loss, _), params, opt = jax.block_until_ready(one_step(params, opt, b))
         one_losses.append(float(loss))
     del params, opt
 
@@ -268,7 +272,7 @@ def phase_data_parallel(chips: int) -> None:
     with mesh:
         for b in batches:
             t0 = time.monotonic()
-            loss, params, opt = jax.block_until_ready(
+            (loss, _), params, opt = jax.block_until_ready(
                 sharded_step(params, opt, jax.device_put(b, bshard))
             )
             times.append(time.monotonic() - t0)

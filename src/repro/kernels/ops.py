@@ -4,7 +4,8 @@
 kernels compile through Mosaic) and to True elsewhere, where the tests run
 them in interpret mode — pass explicitly to override.  These are the
 functions the models call when ``use_pallas`` is enabled (the SSD scan via
-``models.ssm.ssd_pallas``, which adds its backward pass); each has a
+``models.ssm.ssd_pallas``, which adds its backward pass; the grouped
+matmul of the dropless MoE layer); each has a
 pure-jnp oracle in kernels/ref.py with identical signature/semantics.
 """
 from __future__ import annotations
@@ -15,6 +16,7 @@ from typing import Optional
 import jax
 
 from repro.kernels.flash_attention import flash_attention as _flash
+from repro.kernels.gmm import grouped_matmul as _grouped_matmul
 from repro.kernels.ssd import ssd_scan as _ssd
 
 
@@ -49,3 +51,11 @@ def ssd_scan(x, dt, A, Bm, Cm, D, *, chunk: int = 128, interpret: Optional[bool]
     if interpret is None:
         interpret = _default_interpret()
     return _ssd(x, dt, A, Bm, Cm, D, chunk=chunk, interpret=interpret)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, *, tm: int = 128, interpret: Optional[bool] = None):
+    """Rows by group times each group's weight, differentiable (kernels/gmm.py).
+    lhs (M, K), rhs (G, K, N), group_sizes (G,) -> (M, N)."""
+    if interpret is None:
+        interpret = _default_interpret()
+    return _grouped_matmul(lhs, rhs, group_sizes, tm, interpret)

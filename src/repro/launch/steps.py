@@ -88,17 +88,19 @@ def make_train_step(
     remat_policy: str = "minimal",
     microbatches: int = 1,
 ):
-    """fwd+bwd+AdamW.  ``microbatches`` > 1 accumulates gradients over a
+    """fwd+bwd+AdamW: ``(loss, counters), params, opt_state``, where
+    counters are the MoE layers' (``M.train_loss_and_counters``; empty
+    without MoE).  ``microbatches`` > 1 accumulates gradients over a
     lax.scan of batch slices — per-step activation memory drops by the
     factor while arithmetic is unchanged (the standard way the 35-400B
     train_4k cells fit 16 GB/chip HBM)."""
     ctx = make_mesh_context(rules, cfg, global_batch)
     act = act_partition_spec(rules, global_batch)
-    grad_fn = jax.value_and_grad(M.train_loss)
+    grad_fn = jax.value_and_grad(M.train_loss_and_counters, has_aux=True)
 
     def train_step(params, opt_state, batch):
         if microbatches == 1:
-            loss, grads = grad_fn(params, cfg, batch, ctx, act, remat_policy)
+            (loss, counters), grads = grad_fn(params, cfg, batch, ctx, act, remat_policy)
         else:
             def slice_batch(i):
                 def f(x):
@@ -108,22 +110,25 @@ def make_train_step(
 
             def body(carry, i):
                 loss_acc, grads_acc = carry
-                loss_i, grads_i = grad_fn(params, cfg, slice_batch(i), ctx, act, remat_policy)
+                (loss_i, counters_i), grads_i = grad_fn(
+                    params, cfg, slice_batch(i), ctx, act, remat_policy
+                )
                 grads_acc = jax.tree.map(
                     lambda a, g: a + g.astype(jnp.float32), grads_acc, grads_i
                 )
-                return (loss_acc + loss_i, grads_acc), None
+                return (loss_acc + loss_i, grads_acc), counters_i
 
             zeros = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params
             )
-            (loss, grads), _ = jax.lax.scan(
+            (loss, grads), counters = jax.lax.scan(
                 body, (jnp.zeros((), jnp.float32), zeros), jnp.arange(microbatches)
             )
+            counters = {k: v.sum() for k, v in counters.items()}
             loss = loss / microbatches
             grads = jax.tree.map(lambda g: g / microbatches, grads)
         params, opt_state = adamw_update(params, grads, opt_state, settings)
-        return loss, params, opt_state
+        return (loss, counters), params, opt_state
 
     return train_step
 

@@ -29,9 +29,13 @@ class ArchConfig:
     head_dim: int = 0  # 0 => d_model // n_heads
 
     # --- MoE ---------------------------------------------------------------
-    n_experts: int = 0
+    n_experts: int = 0  # experts the router scores
     top_k: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # 0 => dropless: every routed row is computed
+    router: str = "softmax"  # softmax (capacity layer) | sigmoid_bias (dropless layer)
+    routed_scale: float = 1.0  # combine weights of the routed experts are scaled by this
+    shared_expert_ff: int = 0  # width of one shared expert every token passes (0 = none)
+    experts_held: int = 0  # experts whose weights this device holds (0 = all of them)
 
     # --- attention ----------------------------------------------------------
     window: Optional[int] = None  # sliding-window size (None = full)
@@ -39,10 +43,12 @@ class ArchConfig:
     rope_theta: float = 10_000.0
     attn_chunk: int = 1024  # kv-chunk for flash-style chunked attention
     qkv_bias: bool = False
+    pos_emb: str = "rope"  # rope | none
 
     # --- SSM (Mamba-2 / SSD) --------------------------------------------------
     ssm_state: int = 0  # N
     ssm_expand: int = 2
+    ssm_n_heads: int = 0  # 0 => ssm_expand * d_model / ssm_head_dim
     ssm_head_dim: int = 64  # P
     ssm_groups: int = 1  # G
     ssm_conv: int = 4
@@ -51,7 +57,7 @@ class ArchConfig:
     # --- layer pattern --------------------------------------------------------
     period: Tuple[str, ...] = ("attn",)
     mlp_pattern: Tuple[str, ...] = ("mlp",)
-    mlp_act: str = "swiglu"  # swiglu (3 mats) | gelu (2 mats)
+    mlp_act: str = "swiglu"  # swiglu (3 mats) | gelu, relu2 (2 mats: up, down)
 
     # --- embeddings / io -------------------------------------------------------
     frontend: str = "none"  # none | patch (vlm) | frame (audio)
@@ -87,6 +93,21 @@ class ArchConfig:
                 raise ValueError(f"unknown mlp kind {kind!r}")
         if "moe" in self.mlp_pattern and (self.n_experts < 2 or self.top_k < 1):
             raise ValueError("moe layers need n_experts>=2 and top_k>=1")
+        if self.router not in ("softmax", "sigmoid_bias"):
+            raise ValueError(f"unknown router {self.router!r}")
+        if self.pos_emb not in ("rope", "none"):
+            raise ValueError(f"unknown pos_emb {self.pos_emb!r}")
+        if "moe" in self.mlp_pattern and self.capacity_factor > 0:
+            dropless = (self.router, self.routed_scale, self.shared_expert_ff, self.experts_held)
+            if dropless != ("softmax", 1.0, 0, 0):
+                raise ValueError(
+                    "router, routed_scale, shared_expert_ff and experts_held are the dropless "
+                    "layer's (capacity_factor=0)"
+                )
+        elif "moe" in self.mlp_pattern and (
+            self.mlp_act != "relu2" or self.router != "sigmoid_bias"
+        ):
+            raise ValueError("the dropless layer: relu2 experts behind a sigmoid_bias router")
 
     # -- derived -----------------------------------------------------------
     @property
@@ -99,11 +120,22 @@ class ArchConfig:
 
     @property
     def d_inner(self) -> int:
+        if self.ssm_n_heads:
+            return self.ssm_n_heads * self.ssm_head_dim
         return self.ssm_expand * self.d_model
 
     @property
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
+    def mlp_mats(self) -> int:
+        """Weight matrices of one MLP or expert: swiglu 3, gelu and relu2 2."""
+        return 3 if self.mlp_act == "swiglu" else 2
 
     @property
     def has_attention(self) -> bool:
@@ -153,23 +185,31 @@ class ArchConfig:
                 )
             if mlp != "none":
                 total += n * d  # norm2
-            n_mats = 3 if self.mlp_act == "swiglu" else 2
+            n_mats = self.mlp_mats
             if mlp == "mlp":
                 total += n * n_mats * d * self.d_ff
             elif mlp == "moe":
-                total += n * (d * self.n_experts + self.n_experts * n_mats * d * self.d_ff)
+                total += n * (
+                    d * self.n_experts  # router
+                    + self.n_experts_held * n_mats * d * self.d_ff
+                    + 2 * d * self.shared_expert_ff
+                )
+                if self.router == "sigmoid_bias":
+                    total += n * self.n_experts  # selection bias
         return total
 
     def active_param_count(self) -> int:
-        """Parameters touched per token (MoE: top_k of n_experts)."""
+        """Parameters touched per token (MoE: top_k of n_experts, or of the
+        held experts their share top_k * held / n_experts at balance)."""
         if "moe" not in self.mlp_pattern:
             return self.param_count()
         total = self.param_count()
-        n_mats = 3 if self.mlp_act == "swiglu" else 2
-        expert_mats = self.d_model * self.d_ff * n_mats
+        expert_mats = self.d_model * self.d_ff * self.mlp_mats
+        held = self.n_experts_held
+        active = self.top_k * held / self.n_experts
         for mlp in self.mlp_pattern:
             if mlp == "moe":
-                total -= self.n_periods * (self.n_experts - self.top_k) * expert_mats
+                total -= int(self.n_periods * (held - active) * expert_mats)
         return total
 
 

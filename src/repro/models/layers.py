@@ -7,6 +7,7 @@ and activations stay in the config dtype, which is what the MXU wants.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -135,7 +136,8 @@ def chunked_attention(
     l0 = jnp.zeros((B, KV, G, Sq), dtype=jnp.float32)
     acc0 = jnp.zeros((B, KV, G, Sq, hd), dtype=jnp.float32)
     xs = (jnp.arange(n_chunks), jnp.moveaxis(kc, 1, 0), jnp.moveaxis(vc, 1, 0))
-    (m_f, l_f, acc), _ = jax.lax.scan(body, (m0, l0, acc0), xs)
+    # Checkpointed: the backward keeps one chunk's scores at a time too.
+    (m_f, l_f, acc), _ = jax.lax.scan(jax.checkpoint(body), (m0, l0, acc0), xs)
     out = acc / jnp.maximum(l_f, 1e-30)[..., None]  # (B, KV, G, Sq, hd)
     return jnp.moveaxis(out, 3, 1).reshape(B, Sq, H, hd).astype(q.dtype)
 
@@ -156,6 +158,58 @@ def plain_attention(
     p = jnp.where(jnp.isnan(p), 0.0, p)  # fully-masked rows
     o = jnp.einsum("bkgqs,bskh->bkgqh", p, v, preferred_element_type=jnp.float32)
     return jnp.moveaxis(o, 3, 1).reshape(B, Sq, H, hd).astype(q.dtype)
+
+
+#: Query and key block of the Pallas flash-attention forward: at 8k tokens
+#: a 128-block grid has 64x64 steps a head, whose per-step cost outweighs
+#: their work; a 512 block keeps the grid at 16x16 (kernels/flash_attention.py
+#: clamps it to shorter sequences).
+FLASH_BLOCK = 512
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def flash_attention_trainable(q, k, v, causal: bool, window: Optional[int], chunk: int):
+    """The Pallas flash-attention kernel (kernels/flash_attention.py) with a
+    backward pass.  A ``pallas_call`` has no transpose rule, so the forward
+    runs the kernel and the backward differentiates ``chunked_attention``,
+    the XLA form of the same attention, recomputed from the saved inputs
+    one block of ``chunk`` queries at a time (each block against the keys
+    it can see), so that no block's residuals outgrow a chunk's."""
+    from repro.kernels.ops import flash_attention  # lazy: no cycle
+
+    return flash_attention(
+        q, k, v, causal=causal, window=window, block_q=FLASH_BLOCK, block_k=FLASH_BLOCK
+    )
+
+
+def _flash_fwd(q, k, v, causal, window, chunk):
+    return flash_attention_trainable(q, k, v, causal, window, chunk), (q, k, v)
+
+
+def _flash_bwd(causal, window, chunk, inputs, g):
+    q, k, v = inputs
+    S = q.shape[1]
+    block = min(chunk, S)
+    mask = AttnMask(causal=causal, window=window)
+    dq, dk, dv = [], jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)
+    for start in range(0, S, block):
+        stop = min(start + block, S)
+        # A causal block sees the keys up to its last query; slicing the
+        # rest away changes nothing the mask would not drop.
+        seen = stop if causal else k.shape[1]
+
+        def attend(q_blk, k_seen, v_seen, start=start):
+            return chunked_attention(q_blk, k_seen, v_seen, mask, chunk=chunk, q_offset=start)
+
+        _, vjp = jax.vjp(attend, q[:, start:stop], k[:, :seen], v[:, :seen])
+        dq_b, dk_b, dv_b = vjp(g[:, start:stop])
+        dq.append(dq_b)
+        dk = dk.at[:, :seen].add(dk_b.astype(jnp.float32))
+        dv = dv.at[:, :seen].add(dv_b.astype(jnp.float32))
+    return jnp.concatenate(dq, axis=1), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+flash_attention_trainable.defvjp(_flash_fwd, _flash_bwd)
 
 
 def attention_block(
@@ -188,16 +242,15 @@ def attention_block(
     q = q.astype(dt).reshape(B, S, H, hd)
     k = k.astype(dt).reshape(B, S, KV, hd)
     v = v.astype(dt).reshape(B, S, KV, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.pos_emb == "rope":
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
 
     new_cache = None
     if kv_cache is None:
         mask = AttnMask(causal=cfg.causal, window=cfg.window)
         if cfg.use_pallas:
-            from repro.kernels.ops import flash_attention  # lazy: no cycle
-
-            out = flash_attention(q, k, v, causal=cfg.causal, window=cfg.window)
+            out = flash_attention_trainable(q, k, v, cfg.causal, cfg.window, cfg.attn_chunk)
         elif use_chunked and S > cfg.attn_chunk:
             out = chunked_attention(q, k, v, mask, chunk=cfg.attn_chunk)
         else:
@@ -221,15 +274,22 @@ def attention_block(
 # ---------------------------------------------------------------------------
 # MLPs
 # ---------------------------------------------------------------------------
+def activation(u: jax.Array, act: str) -> jax.Array:
+    """The nonlinearity of a 2-matrix MLP: gelu, or relu2 (squared ReLU)."""
+    if act == "relu2":
+        return jnp.square(jax.nn.relu(u))
+    return jax.nn.gelu(u)
+
+
 def mlp_block(params: dict, x: jax.Array, cfg: ArchConfig) -> jax.Array:
     dt = x.dtype
     if cfg.mlp_act == "swiglu":
         g = jnp.einsum("bsd,df->bsf", x, params["w_gate"], preferred_element_type=jnp.float32)
         u = jnp.einsum("bsd,df->bsf", x, params["w_up"], preferred_element_type=jnp.float32)
         h = (jax.nn.silu(g) * u).astype(dt)
-    else:  # gelu: classic 2-matrix MLP (encoder stacks)
+    else:  # gelu (encoder stacks) or relu2: 2-matrix MLP
         u = jnp.einsum("bsd,df->bsf", x, params["w_up"], preferred_element_type=jnp.float32)
-        h = jax.nn.gelu(u).astype(dt)
+        h = activation(u, cfg.mlp_act).astype(dt)
     y = jnp.einsum("bsf,fd->bsd", h, params["w_down"], preferred_element_type=jnp.float32)
     return y.astype(dt)
 

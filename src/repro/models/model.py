@@ -12,8 +12,10 @@ Entry points (all pure functions over plain dict pytrees):
 
   init_params / param_shapes     parameters (real / ShapeDtypeStruct)
   forward                        token/frame embeddings -> final hidden
+  forward_and_counters           ... and the MoE layers' counters
   train_loss                     chunked-vocab cross entropy (never
                                  materializes (B,S,V) for the full sequence)
+  train_loss_and_counters        ... and the MoE layers' counters
   prefill                        forward + KV/SSM decode state
   init_decode_state / decode_step one-token serving step
 """
@@ -33,7 +35,7 @@ from repro.models.layers import (
     mlp_param_shapes,
     rms_norm,
 )
-from repro.models.moe import MeshContext, moe_block, moe_param_shapes
+from repro.models.moe import MeshContext, moe_layer, moe_param_shapes
 from repro.models.ssm import (
     ssm_block,
     ssm_block_decode,
@@ -44,7 +46,7 @@ from repro.models.ssm import (
 # ---------------------------------------------------------------------------
 # Parameter construction
 # ---------------------------------------------------------------------------
-_F32_LEAVES = ("A_log", "D", "dt_bias")  # small SSM params stay f32
+_F32_LEAVES = ("A_log", "D", "dt_bias", "router_bias")  # small SSM / router params stay f32
 
 
 def _mixer_shapes(cfg: ArchConfig, kind: str) -> dict:
@@ -74,7 +76,7 @@ def _init_leaf(key, name: str, shape, cfg: ArchConfig, stacked: int = 0):
     dt = jnp.float32 if name in _F32_LEAVES else cfg.jnp_dtype
     if name.startswith("norm") or name in ("gate_norm", "final_norm"):
         return jnp.ones(full, dt)
-    if name in ("conv_b", "dt_bias") or name.startswith("b"):
+    if name in ("conv_b", "dt_bias", "router_bias") or name.startswith("b"):
         return jnp.zeros(full, dt)
     if name == "A_log":
         return jnp.zeros(full, dt)  # A = -exp(0) = -1
@@ -140,10 +142,12 @@ def embed_inputs(params: Dict, cfg: ArchConfig, batch: Dict) -> jax.Array:
 # The period body (one repetition of cfg.period)
 # ---------------------------------------------------------------------------
 def _channel_mix(p: dict, x, cfg: ArchConfig, kind: str, ctx: Optional[MeshContext]):
+    """(x + channel mixer, counters): the MoE layer's counters, else none."""
     h = rms_norm(x, p["norm2"], cfg.norm_eps)
     if kind == "moe":
-        return x + moe_block(p["mlp"], h, cfg, ctx)
-    return x + mlp_block(p["mlp"], h, cfg)
+        y, counters = moe_layer(p["mlp"], h, cfg, ctx)
+        return x + y, counters
+    return x + mlp_block(p["mlp"], h, cfg), {}
 
 
 def _period_forward(
@@ -157,9 +161,10 @@ def _period_forward(
 ):
     """One period over a full sequence (train / prefill).
 
-    Returns (x, caches) where caches[f"pos{i}"] holds the decode carry for
-    position ``i`` (attn: dict(k,v); ssm: dict(state,conv)) when
-    ``collect_cache`` — else an empty dict.
+    Returns (x, caches, counters) where caches[f"pos{i}"] holds the decode
+    carry for position ``i`` (attn: dict(k,v); ssm: dict(state,conv)) when
+    ``collect_cache`` — else an empty dict — and counters sums the
+    period's MoE counters (``moe_layer``).
 
     ``inner_remat`` additionally checkpoints every SUBLAYER, so the backward
     pass holds one sublayer's FSDP-gathered weights + intermediates at a
@@ -171,7 +176,7 @@ def _period_forward(
     def ck(f, *args):
         return jax.checkpoint(f)(*args) if inner_remat else f(*args)
 
-    caches = {}
+    caches, counters = {}, {}
     for i, (mixer, mlp) in enumerate(zip(cfg.period, cfg.mlp_pattern)):
         p = pslice[f"pos{i}"]
         h = rms_norm(x, p["norm1"], cfg.norm_eps)
@@ -195,7 +200,8 @@ def _period_forward(
                 if cfg.qkv_bias:
                     k = k + p["mixer"]["bk"].reshape(1, 1, cfg.n_kv_heads, cfg.head_dim)
                     v = v + p["mixer"]["bv"].reshape(1, 1, cfg.n_kv_heads, cfg.head_dim)
-                k = apply_rope(k, positions, cfg.rope_theta)
+                if cfg.pos_emb == "rope":
+                    k = apply_rope(k, positions, cfg.rope_theta)
                 caches[f"pos{i}"] = {"k": k.astype(cfg.jnp_dtype), "v": v.astype(cfg.jnp_dtype)}
         else:
             y, carry = ck(lambda pm, hh: ssm_block(pm, hh, cfg), p["mixer"], h)
@@ -203,10 +209,11 @@ def _period_forward(
                 caches[f"pos{i}"] = {"state": carry[0], "conv": carry[1]}
         x = x + y
         if mlp != "none":
-            x = ck(
+            x, more = ck(
                 lambda pp, xx, kind=mlp: _channel_mix(pp, xx, cfg, kind, ctx), p, x
             )
-    return x, caches
+            counters = {k: counters.get(k, 0) + v for k, v in more.items()} or counters
+    return x, caches, counters
 
 
 def _period_decode(
@@ -241,14 +248,22 @@ def _period_decode(
             new_caches[f"pos{i}"] = {"state": carry[0], "conv": carry[1]}
         x = x + y
         if mlp != "none":
-            x = _channel_mix(p, x, cfg, mlp, ctx)
+            x, _ = _channel_mix(p, x, cfg, mlp, ctx)
     return x, new_caches
 
 
 # ---------------------------------------------------------------------------
 # Full forward passes
 # ---------------------------------------------------------------------------
-def forward(
+def forward(params: Dict, cfg: ArchConfig, batch: Dict, ctx: Optional[MeshContext] = None,
+            **kwargs):
+    """Embeddings -> scanned stack -> final norm: ``forward_and_counters``
+    without the counters."""
+    hidden, caches, _ = forward_and_counters(params, cfg, batch, ctx, **kwargs)
+    return hidden, caches
+
+
+def forward_and_counters(
     params: Dict,
     cfg: ArchConfig,
     batch: Dict,
@@ -261,8 +276,9 @@ def forward(
 ):
     """Embeddings -> scanned stack -> final norm.
 
-    Returns (hidden (B,S,d), caches) — caches stacked over periods when
-    ``collect_cache`` (prefill), else None.
+    Returns (hidden (B,S,d), caches, counters) — caches stacked over
+    periods when ``collect_cache`` (prefill), else None; counters the MoE
+    layers' counters summed over the stack (empty without MoE).
 
     ``act_spec`` (a PartitionSpec for (B, S, d)) pins the activation
     sharding at every period boundary — without it GSPMD is free to
@@ -289,10 +305,10 @@ def forward(
     inner = remat and remat_policy == "sublayer"
 
     def body(carry, pslice):
-        y, caches = _period_forward(
+        y, caches, counters = _period_forward(
             pslice, carry, cfg, ctx, positions, collect_cache, inner_remat=inner
         )
-        return constrain(y), (caches if collect_cache else None)
+        return constrain(y), (caches if collect_cache else None, counters)
 
     if remat:
         policy = (
@@ -301,9 +317,10 @@ def forward(
             else None
         )
         body = jax.checkpoint(body, policy=policy)
-    x, caches = jax.lax.scan(body, x, params["stack"])
+    x, (caches, counters) = jax.lax.scan(body, x, params["stack"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return x, (caches if collect_cache else None)
+    counters = {k: v.sum() for k, v in counters.items()}
+    return x, (caches if collect_cache else None), counters
 
 
 def lm_head(params: Dict, cfg: ArchConfig, hidden: jax.Array) -> jax.Array:
@@ -363,10 +380,22 @@ def train_loss(
     act_spec=None,
     remat_policy: Optional[str] = "minimal",
 ) -> jax.Array:
-    hidden, _ = forward(
+    return train_loss_and_counters(params, cfg, batch, ctx, act_spec, remat_policy)[0]
+
+
+def train_loss_and_counters(
+    params: Dict,
+    cfg: ArchConfig,
+    batch: Dict,
+    ctx: Optional[MeshContext] = None,
+    act_spec=None,
+    remat_policy: Optional[str] = "minimal",
+):
+    """(loss, counters): the training loss and the stack's MoE counters."""
+    hidden, _, counters = forward_and_counters(
         params, cfg, batch, ctx, remat=True, act_spec=act_spec, remat_policy=remat_policy
     )
-    return chunked_ce_loss(params, cfg, hidden, batch["labels"])
+    return chunked_ce_loss(params, cfg, hidden, batch["labels"]), counters
 
 
 # ---------------------------------------------------------------------------

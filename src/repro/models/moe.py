@@ -1,6 +1,12 @@
 """Mixture-of-Experts FFN with TPU-native expert parallelism.
 
-Routing is top-k with capacity-based token dropping (GShard-style,
+Two layers.  ``moe_layer`` picks by ``capacity_factor``: above 0 the
+capacity layer below; 0 the dropless layer (``routed_experts``), which
+holds ``experts_held`` of the experts, routes over all of them, computes
+every row routed to its own through grouped matmuls, and adds a shared
+expert.
+
+Capacity layer: routing is top-k with capacity-based token dropping (GShard-style,
 first-come-first-served by sequence position), expressed WITHOUT the giant
 (tokens, experts, capacity) one-hot dispatch tensors: each expert *selects*
 its assigned tokens with ``lax.top_k`` over an assignment score, computes a
@@ -27,7 +33,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.gmm import group_starts as gmm_group_starts
+from repro.kernels.ops import grouped_matmul
 from repro.models.config import ArchConfig
+from repro.models.layers import activation
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,6 +131,101 @@ def _moe_local(params, x_flat, cfg: ArchConfig, capacity: int, n_local: int, e0)
     return jax.lax.fori_loop(0, n_local, one_expert, acc0)
 
 
+# ---------------------------------------------------------------------------
+# Dropless: every routed row on a held expert is computed
+# ---------------------------------------------------------------------------
+#: Rows of a grouped-matmul tile: each held expert's rows start on one.
+GMM_TILE = 128
+
+
+def route(x_flat: jax.Array, params: dict, cfg: ArchConfig):
+    """Top-k over all ``cfg.n_experts`` -> (topk_idx (T,k) int32, weights (T,k) f32).
+
+    The ``sigmoid_bias`` router (DeepSeek-V3 and Nemotron-H): sigmoid scores
+    in f32; ``router_bias`` is added for the choice only; the chosen scores
+    are normalised to sum 1 and scaled by ``routed_scale``.
+    """
+    logits = jnp.einsum("td,de->te", x_flat, params["router"], preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + params["router_bias"].astype(jnp.float32), cfg.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return idx, w * cfg.routed_scale
+
+
+def _dropless_rows(idx, w, e0, held: int, tm: int):
+    """Lay the (token, slot) assignments that land on experts [e0, e0+held)
+    out by expert, each expert's rows from a tile boundary (``gmm``'s
+    layout).  Returns (token (M,), weight (M,), sizes (held,)): the token
+    each row computes (T for a row that holds none) and its combine weight
+    (0 there)."""
+    T, k = idx.shape
+    local = idx.reshape(-1) - e0  # (T*k,)
+    mine = (local[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)  # (T*k, held)
+    sizes = mine.sum(0)
+    rank = jnp.sum((jnp.cumsum(mine, axis=0) - 1) * mine, axis=1)  # place within its expert
+    held_here = (local >= 0) & (local < held)
+    n_rows = -(-(T * min(k, held) + held * (tm - 1)) // tm) * tm  # the most any routing needs
+    dest = jnp.where(
+        held_here, gmm_group_starts(sizes, tm)[jnp.clip(local, 0, held - 1)] + rank, n_rows
+    )
+    token = jnp.full((n_rows,), T, jnp.int32).at[dest].set(
+        jnp.arange(T * k, dtype=jnp.int32) // k, mode="drop"
+    )
+    weight = jnp.zeros((n_rows,), jnp.float32).at[dest].set(w.reshape(-1), mode="drop")
+    return token, weight, sizes
+
+
+def routed_experts(params: dict, x_flat: jax.Array, cfg: ArchConfig, e0=0):
+    """The held experts' part of the layer, dropless: route over every
+    expert, sort the rows that land on experts [e0, e0 + held) by expert,
+    run each expert's relu2 MLP as two grouped matmuls (kernels/gmm.py),
+    and scatter-add the rows back weighted.  Returns ((T, d) f32, counters):
+    ``moe_rows`` the rows computed, ``moe_rows_max`` the largest expert's,
+    ``moe_experts`` the held experts with any (whose weights are read)."""
+    T, d = x_flat.shape
+    held = cfg.n_experts_held
+    with jax.named_scope("moe.route"):
+        idx, w = route(x_flat, params, cfg)
+    with jax.named_scope("moe.dispatch"):
+        token, weight, sizes = _dropless_rows(idx, w, e0, held, GMM_TILE)
+        rows = jnp.take(x_flat, token, axis=0, mode="fill", fill_value=0)
+    with jax.named_scope("moe.gmm"):
+        h = grouped_matmul(rows, params["w_up"], sizes, tm=GMM_TILE)
+        h = activation(h.astype(jnp.float32), cfg.mlp_act).astype(x_flat.dtype)
+        y = grouped_matmul(h, params["w_down"], sizes, tm=GMM_TILE)
+    with jax.named_scope("moe.combine"):
+        out = jnp.zeros((T, d), jnp.float32).at[token].add(
+            y.astype(jnp.float32) * weight[:, None], mode="drop"
+        )
+    counters = {"moe_rows": sizes.sum(), "moe_rows_max": sizes.max(),
+                "moe_experts": jnp.sum(sizes > 0)}
+    return out, counters
+
+
+def shared_expert(params: dict, x_flat: jax.Array, cfg: ArchConfig) -> jax.Array:
+    """The shared expert every token passes, (T, d) f32."""
+    with jax.named_scope("moe.shared"):
+        h = jnp.einsum("td,df->tf", x_flat, params["shared_up"], preferred_element_type=jnp.float32)
+        h = activation(h, cfg.mlp_act).astype(x_flat.dtype)
+        return jnp.einsum("tf,fd->td", h, params["shared_down"], preferred_element_type=jnp.float32)
+
+
+def moe_layer(params: dict, x: jax.Array, cfg: ArchConfig, ctx: Optional[MeshContext] = None):
+    """The MoE FFN and its counters: ``(y (B,S,d), counters)``.  Counters are
+    empty for the capacity layer."""
+    if cfg.capacity_factor > 0:
+        return moe_block(params, x, cfg, ctx), {}
+    if ctx is not None and ctx.model_size > 1:
+        raise NotImplementedError("the dropless layer runs on one device's expert share")
+    B, S, d = x.shape
+    x_flat = x.reshape(B * S, d)
+    y, counters = routed_experts(params, x_flat, cfg)
+    if cfg.shared_expert_ff:
+        y = y + shared_expert(params, x_flat, cfg)
+    return y.astype(x.dtype).reshape(B, S, d), counters
+
+
 def moe_block(
     params: dict,
     x: jax.Array,
@@ -129,7 +233,7 @@ def moe_block(
     ctx: Optional[MeshContext] = None,
     capacity_factor: Optional[float] = None,
 ) -> jax.Array:
-    """MoE FFN. params: router (d,E), w_gate/w_up (E,d,f), w_down (E,f,d)."""
+    """MoE FFN with capacity. params: router (d,E), w_gate/w_up (E,d,f), w_down (E,f,d)."""
     B, S, d = x.shape
     dt = x.dtype
 
@@ -173,10 +277,18 @@ def moe_block(
 
 
 def moe_param_shapes(cfg: ArchConfig) -> dict:
-    d, f, E = cfg.d_model, cfg.d_ff, cfg.n_experts
-    shapes = {"router": (d, E), "w_gate": (E, d, f), "w_down": (E, f, d)}
+    d, f, E, held = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.n_experts_held
+    if cfg.mlp_act == "relu2":
+        shapes = {"router": (d, E), "w_up": (held, d, f), "w_down": (held, f, d)}
+    else:
+        shapes = {"router": (d, E), "w_gate": (held, d, f), "w_down": (held, f, d)}
     if cfg.mlp_act == "swiglu":
-        shapes["w_up"] = (E, d, f)
+        shapes["w_up"] = (held, d, f)
+    if cfg.router == "sigmoid_bias":
+        shapes["router_bias"] = (E,)
+    if cfg.shared_expert_ff:
+        shapes["shared_up"] = (d, cfg.shared_expert_ff)
+        shapes["shared_down"] = (cfg.shared_expert_ff, d)
     return shapes
 
 

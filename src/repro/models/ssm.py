@@ -176,6 +176,18 @@ def ssd_decode_step(state, x, dt, A, Bm, Cm, D):
 # ---------------------------------------------------------------------------
 # The full Mamba-2 block (in_proj -> conv -> SSD -> gated norm -> out_proj)
 # ---------------------------------------------------------------------------
+def gated_rms_norm(x: jax.Array, scale: jax.Array, eps: float, groups: int) -> jax.Array:
+    """RMSNorm of the gated SSD output over each B/C group's channels
+    (mamba_ssm's ``RMSNormGated`` with ``group_size = d_inner / ngroups``).
+    One group is the plain RMSNorm over all channels."""
+    if groups == 1:
+        return rms_norm(x, scale, eps)
+    shape = x.shape
+    y = x.astype(jnp.float32).reshape(*shape[:-1], groups, shape[-1] // groups)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
+    return (y.reshape(shape) * scale.astype(jnp.float32)).astype(x.dtype)
+
+
 def ssm_param_shapes(cfg: ArchConfig) -> dict:
     d, di = cfg.d_model, cfg.d_inner
     G, N, H = cfg.ssm_groups, cfg.ssm_state, cfg.ssm_heads
@@ -254,7 +266,7 @@ def ssm_block(
             xh, dtv, A, Bg, Cg, params["D"].astype(jnp.float32), chunk=chunk
         )
     y = y[:, :S].reshape(Bb, S, di).astype(dt0)
-    y = rms_norm(y * jax.nn.silu(z), params["gate_norm"], cfg.norm_eps)
+    y = gated_rms_norm(y * jax.nn.silu(z), params["gate_norm"], cfg.norm_eps, G)
     out = jnp.einsum(
         "bse,ed->bsd", y, params["out_proj"], preferred_element_type=jnp.float32
     ).astype(dt0)
@@ -297,7 +309,7 @@ def ssm_block_decode(
         params["D"].astype(jnp.float32),
     )
     y = y.reshape(Bb, 1, di)
-    y = rms_norm((y * jax.nn.silu(z)).astype(dt0), params["gate_norm"], cfg.norm_eps)
+    y = gated_rms_norm((y * jax.nn.silu(z)).astype(dt0), params["gate_norm"], cfg.norm_eps, G)
     out = jnp.einsum(
         "bse,ed->bsd", y, params["out_proj"], preferred_element_type=jnp.float32
     ).astype(dt0)

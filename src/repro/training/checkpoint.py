@@ -102,7 +102,9 @@ class AsyncCheckpointer:
 
     def save(self, step: int, params, opt_state, loader_state=None, rng=None, extra=None):
         self.wait()
-        host = jax.tree.map(lambda x: np.asarray(x), {"p": params, "o": opt_state})
+        # A copy of its own, taken now: the next step may be handed the
+        # device buffers (donated) and write over them.
+        host = jax.tree.map(lambda x: np.array(x, copy=True), {"p": params, "o": opt_state})
 
         def work():
             try:

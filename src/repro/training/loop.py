@@ -45,6 +45,11 @@ class StepMetrics:
     misses: int
     prepare_s: float  # the train.prepare span: decode, stack, copy to the device
     dispatch_s: float  # the train.dispatch span: the jitted step's call until it returns
+    # The step's counters, read with the loss: the MoE layers' held-expert
+    # rows (moe_rows), largest expert's rows (moe_rows_max) and held experts
+    # with rows (moe_experts), each summed over the layers; empty for a
+    # model without MoE.
+    counters: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 @contextlib.contextmanager
@@ -89,7 +94,9 @@ class Trainer:
         self.opt_state = adamw_init(self.params, self.settings)
         self.step = 0
         self.metrics: List[StepMetrics] = []
-        self._step_fn = jax.jit(make_train_step(cfg, self.settings))
+        # The step takes the parameters and the optimizer state over: the
+        # state is held once.  Whoever reads them reads them between steps.
+        self._step_fn = jax.jit(make_train_step(cfg, self.settings), donate_argnums=(0, 1))
         self._ckpt = (
             ckpt.AsyncCheckpointer(tcfg.checkpoint_dir, keep=tcfg.keep_checkpoints)
             if tcfg.checkpoint_dir
@@ -169,14 +176,16 @@ class Trainer:
             with _phase("train.dispatch", seconds):
                 out = self._step_fn(self.params, self.opt_state, dev_batch)
             with _phase("train.block", seconds):
-                loss, self.params, self.opt_state = jax.block_until_ready(out)
+                (loss, counters), self.params, self.opt_state = jax.block_until_ready(out)
             with _phase("train.read_loss", seconds):
+                loss, counters = jax.device_get((loss, counters))
                 loss = float(loss)
             compute_s = time.perf_counter() - t0
             self.step += 1
             m = StepMetrics(
                 self.step, loss, batch.data_wait_s, compute_s, batch.hits, batch.misses,
                 prepare_s=seconds["train.prepare"], dispatch_s=seconds["train.dispatch"],
+                counters={k: int(v) for k, v in counters.items()},
             )
             self.metrics.append(m)
             if self.step % self.tcfg.log_every == 0:

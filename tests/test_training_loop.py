@@ -170,3 +170,53 @@ def test_epoch_end_stops_the_call_as_before():
         metrics = t.train(100, epochs=2)
     assert len(metrics) == 32 and t.step == 32
     assert [m.step for m in metrics] == list(range(1, 33))
+
+
+def test_the_step_takes_the_state_over_and_reads_counters():
+    """The jitted step is handed the parameters and optimizer state
+    (donated): after a step the old arrays are gone, the new ones live.  A
+    model without MoE layers has no step counters; a dropless MoE model
+    reads its held-expert rows with the loss."""
+    t, svc = _trainer()
+    old = jax.tree.leaves((t.params, t.opt_state))
+    with svc:
+        t.train(1)
+    assert all(x.is_deleted() for x in old)
+    assert not any(x.is_deleted() for x in jax.tree.leaves((t.params, t.opt_state)))
+    assert t.metrics[-1].counters == {}
+
+    moe_cfg = dataclasses.replace(
+        CFG, family="moe", mlp_pattern=("moe",), n_experts=4, top_k=2, capacity_factor=0.0,
+        router="sigmoid_bias", experts_held=2, mlp_act="relu2", d_ff=32,
+    )
+    t, svc = _trainer(cfg=moe_cfg)
+    with svc:
+        t.train(2)
+    tokens = BATCH * SEQ
+    for m in t.metrics:
+        assert set(m.counters) == {"moe_rows", "moe_rows_max", "moe_experts"}
+        # 2 layers, each routing 2 of 4 experts a token over 2 held experts
+        assert 0 < m.counters["moe_rows"] <= 2 * 2 * tokens
+        assert m.counters["moe_rows"] / 4 <= m.counters["moe_rows_max"] <= m.counters["moe_rows"]
+        assert 1 <= m.counters["moe_experts"] <= 4
+
+
+def test_checkpoint_copies_the_state_before_the_next_step_takes_it():
+    """``AsyncCheckpointer.save`` holds its own host copy: the next step may
+    be handed (and write over) the device buffers while the write runs."""
+    d = tempfile.mkdtemp()
+    t, svc = _trainer(ckpt_dir=d, every=1000)
+    with svc:
+        t.train(2)
+    before = [np.array(x) for x in jax.tree.leaves((t.params, t.opt_state))]
+    donated = jax.tree.leaves((t.params, t.opt_state))
+    t._ckpt.save(t.step, t.params, t.opt_state)
+    with svc:
+        t.train(1)  # runs while the write may still be going
+    t._ckpt.wait()
+    assert all(x.is_deleted() for x in donated)
+    params, opt, _, _ = ckpt.restore_checkpoint(d, 2, like=(t.params, t.opt_state))
+    restored = [np.asarray(x) for x in jax.tree.leaves((params, opt))]
+    assert len(restored) == len(before)
+    for a, b in zip(before, restored):
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
