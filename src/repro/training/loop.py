@@ -8,13 +8,24 @@ is the bottleneck, exactly the measurement DELI §V makes — but per training
 step instead of per epoch, because a pod-scale job wants to see data-wait
 within the step budget, not after an epoch is lost.
 
-Each step is a profiler step span ``train.step`` (its ``step_num`` is the
-step), and each host phase of it a span inside: ``train.next_batch`` (the
+The loop runs one step ahead on JAX's async dispatch: while step n runs on
+the device, the host requests batch n+1 from the loader, prepares it and
+dispatches step n+1 on step n's parameters and moments (still futures, and
+donated), and only then waits for step n's loss.  It drains (waits for the
+step in flight and requests nothing more) where the loader ends the epoch,
+at the call's last step, before a checkpoint, and where an exception leaves
+the loop; so between steps the state is what it always was: at batch
+request k the parameters and moments are those after k-1 steps.
+
+Each pass of the loop is a profiler step span ``train.step`` (its
+``step_num`` is the step it requests), and each host phase a span inside,
+with the ``step_num`` of the step it serves: ``train.next_batch`` (the
 request to the loader), ``train.prepare`` (decode, stack, the copy to the
-device), ``train.dispatch`` (the call of the jitted step), ``train.block``
-(waiting for its result) and ``train.read_loss``.  They land in the same
-trace as the device's operations, on its clock, whenever a profiler trace
-runs, and cost a few microseconds each when none does.
+device), ``train.dispatch`` (the call of the jitted step), then
+``train.block`` (waiting for the step before's result) and
+``train.read_loss`` of that step.  They land in the same trace as the
+device's operations, on its clock, whenever a profiler trace runs, and cost
+a few microseconds each when none does.
 """
 from __future__ import annotations
 
@@ -40,7 +51,7 @@ class StepMetrics:
     step: int
     loss: float
     data_wait_s: float
-    compute_s: float
+    compute_s: float  # the step's own host time: dispatch_s and its block and loss read
     hits: int
     misses: int
     prepare_s: float  # the train.prepare span: decode, stack, copy to the device
@@ -50,16 +61,31 @@ class StepMetrics:
     # with rows (moe_experts), each summed over the layers; empty for a
     # model without MoE.
     counters: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # True where the next batch was requested while this step ran on the
+    # device; False where the loop drained before requesting it.
+    overlapped: bool = False
 
 
 @contextlib.contextmanager
-def _phase(name: str, seconds: Dict[str, float]) -> Iterator[None]:
-    """A profiler span ``name`` around the block, whose host duration goes
-    to ``seconds[name]``: a span and its counter cannot drift apart."""
+def _phase(name: str, step: int, seconds: Dict[str, float]) -> Iterator[None]:
+    """A profiler span ``name`` of step ``step`` around the block, whose host
+    duration goes to ``seconds[name]``: a span and its counter cannot drift
+    apart."""
     t0 = time.perf_counter()
-    with jax.profiler.TraceAnnotation(name):
+    with jax.profiler.TraceAnnotation(name, step_num=step):
         yield
     seconds[name] = time.perf_counter() - t0
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """A step dispatched and not yet read.  Its parameters and moments have
+    gone to ``Trainer``; ``out`` is its ``(loss, counters)``, still futures."""
+
+    step: int
+    batch: Batch
+    out: tuple
+    seconds: Dict[str, float]  # its phases' host times so far
 
 
 @dataclasses.dataclass
@@ -132,8 +158,11 @@ class Trainer:
             self.loader.load_state_dict(loader_state)
         return True
 
+    def _checkpoint_due(self, step: int) -> bool:
+        return self._ckpt is not None and step % self.tcfg.checkpoint_every == 0
+
     def _maybe_checkpoint(self):
-        if self._ckpt and self.step % self.tcfg.checkpoint_every == 0:
+        if self._checkpoint_due(self.step):
             self._ckpt.save(
                 self.step,
                 self.params,
@@ -148,55 +177,81 @@ class Trainer:
         epoch = self.loader.state_dict()["epoch"]
         while self.step < target and epoch < epochs:
             self.loader.set_epoch(epoch)
-            batches = iter(self.loader)
-            while self._train_step(batches) and self.step < target:
-                pass
+            self._run_epoch(iter(self.loader), target)
             epoch += 1
         if self._ckpt:
             self._ckpt.wait()
         return self.metrics
 
-    def _train_step(self, batches: Iterator[Batch]) -> bool:
-        """One step from the loader's next batch; False where there is none.
+    def _run_epoch(self, batches: Iterator[Batch], target: int) -> None:
+        """Steps from ``batches`` until it ends or step ``target`` is read,
+        one step ahead.
 
-        ``compute_s`` runs from the call of the jitted step to the end of
-        the loss read: the device step as the host sees it.  A request that
-        finds no batch still lies in a ``train.step`` span, since the span
-        opens before the request and a profiler span cannot be withdrawn.
+        A pass requests, prepares and dispatches step n, then reads step
+        n-1, which ran on the device meanwhile.  Step n is read in its own
+        pass, before anything more is requested, where it is ``target`` or
+        a checkpoint is due after it.  A request that finds no batch still
+        lies in a ``train.step`` span, since the span opens before the
+        request and a profiler span cannot be withdrawn.
         """
-        seconds: Dict[str, float] = {}
-        with jax.profiler.StepTraceAnnotation("train.step", step_num=self.step + 1):
-            with _phase("train.next_batch", seconds):
-                batch = next(batches, None)
-            if batch is None:
-                return False
-            with _phase("train.prepare", seconds):
-                dev_batch = self._to_device_batch(batch)
-            t0 = time.perf_counter()
-            with _phase("train.dispatch", seconds):
-                out = self._step_fn(self.params, self.opt_state, dev_batch)
-            with _phase("train.block", seconds):
-                (loss, counters), self.params, self.opt_state = jax.block_until_ready(out)
-            with _phase("train.read_loss", seconds):
-                loss, counters = jax.device_get((loss, counters))
-                loss = float(loss)
-            compute_s = time.perf_counter() - t0
-            self.step += 1
-            m = StepMetrics(
-                self.step, loss, batch.data_wait_s, compute_s, batch.hits, batch.misses,
-                prepare_s=seconds["train.prepare"], dispatch_s=seconds["train.dispatch"],
-                counters={k: int(v) for k, v in counters.items()},
+        flights: List[_InFlight] = []  # dispatched and not yet read, oldest first
+        try:
+            while self.step < target:
+                n = self.step + 1 + len(flights)
+                with jax.profiler.StepTraceAnnotation("train.step", step_num=n):
+                    seconds: Dict[str, float] = {}
+                    with _phase("train.next_batch", n, seconds):
+                        batch = next(batches, None)
+                    if batch is not None:
+                        flights.append(self._dispatch(n, batch, seconds))
+                    if flights and flights[0].step < n:
+                        self._finish(flights.pop(0), overlapped=True)
+                    if batch is None:
+                        return
+                    if n == target or self._checkpoint_due(n):
+                        self._finish(flights.pop(), overlapped=False)
+                        self._maybe_checkpoint()
+        finally:
+            while flights:
+                self._finish(flights.pop(0), overlapped=False)
+
+    def _dispatch(self, n: int, batch: Batch, seconds: Dict[str, float]) -> _InFlight:
+        """Prepare step ``n``'s batch and dispatch the step; the trainer's
+        state becomes the step's (futures) at once."""
+        with _phase("train.prepare", n, seconds):
+            dev_batch = self._to_device_batch(batch)
+        with _phase("train.dispatch", n, seconds):
+            out, self.params, self.opt_state = self._step_fn(
+                self.params, self.opt_state, dev_batch
             )
-            self.metrics.append(m)
-            if self.step % self.tcfg.log_every == 0:
-                print(
-                    f"step {self.step} loss {loss:.4f} "
-                    f"wait {m.data_wait_s*1e3:.1f}ms prepare {m.prepare_s*1e3:.1f}ms "
-                    f"dispatch {m.dispatch_s*1e3:.1f}ms step {m.compute_s*1e3:.1f}ms "
-                    f"miss {batch.misses}/{batch.hits + batch.misses}"
-                )
-            self._maybe_checkpoint()
-        return True
+        return _InFlight(n, batch, out, seconds)
+
+    def _finish(self, flight: _InFlight, overlapped: bool) -> None:
+        """Wait for a step in flight, read its loss and counters, and record it."""
+        seconds = flight.seconds
+        with _phase("train.block", flight.step, seconds):
+            jax.block_until_ready(flight.out)
+        with _phase("train.read_loss", flight.step, seconds):
+            loss, counters = jax.device_get(flight.out)
+            loss = float(loss)
+        self.step += 1
+        batch = flight.batch
+        m = StepMetrics(
+            self.step, loss, batch.data_wait_s,
+            seconds["train.dispatch"] + seconds["train.block"] + seconds["train.read_loss"],
+            batch.hits, batch.misses,
+            prepare_s=seconds["train.prepare"], dispatch_s=seconds["train.dispatch"],
+            counters={k: int(v) for k, v in counters.items()},
+            overlapped=overlapped,
+        )
+        self.metrics.append(m)
+        if self.step % self.tcfg.log_every == 0:
+            print(
+                f"step {self.step} loss {loss:.4f} "
+                f"wait {m.data_wait_s*1e3:.1f}ms prepare {m.prepare_s*1e3:.1f}ms "
+                f"dispatch {m.dispatch_s*1e3:.1f}ms step {m.compute_s*1e3:.1f}ms "
+                f"miss {batch.misses}/{batch.hits + batch.misses}"
+            )
 
     # -- paper metrics ------------------------------------------------------------
     def epoch_wait_summary(self) -> Dict[int, float]:

@@ -129,9 +129,12 @@ PHASES = ("train.next_batch", "train.prepare", "train.dispatch", "train.block", 
 
 
 def test_each_step_and_its_phases_are_profiler_spans(tmp_path):
-    """Under a profiler trace every step is one ``train.step`` span with its
-    ``step_num``, holding one span of each host phase; the phase counters
-    agree with what they time."""
+    """Under a profiler trace each pass of the loop is one ``train.step`` span
+    with the ``step_num`` of the step it requests, and every phase span lies
+    in one.  Each step has one span of each phase, tagged with its
+    ``step_num``; step n is waited for and read only after step n+1 is
+    dispatched.  Each phase counter spans its phase's span and no more than
+    the host time between the spans on either side of it."""
     from jax.profiler import ProfileData
 
     t, svc = _trainer()
@@ -153,13 +156,137 @@ def test_each_step_and_its_phases_are_profiler_spans(tmp_path):
     ]
     steps = sorted((s for s in spans if s[0] == "train.step"), key=lambda s: s[1])
     assert [s[3].get("step_num") for s in steps] == [2, 3, 4]
-    for _, a, b, _ in steps:
-        inside = sorted((s for s in spans if s[0] in PHASES and a <= s[1] and s[2] <= b),
-                        key=lambda s: s[1])
-        assert [s[0] for s in inside] == list(PHASES)
-    assert sum(s[0] in PHASES for s in spans) == 3 * len(PHASES)
+    phases = sorted((s for s in spans if s[0] in PHASES), key=lambda s: s[1])
+    assert len(phases) == 3 * len(PHASES)
+    for _, a, b, _ in phases:
+        assert any(sa <= a and b <= sb for _, sa, sb, _ in steps)
+    of = {(s[0], s[3].get("step_num")): s for s in phases}
+    assert set(of) == {(p, n) for p in PHASES for n in (2, 3, 4)}
+    for n in (2, 3):
+        dispatched_next = of["train.dispatch", n + 1][2]
+        assert of["train.block", n][1] >= dispatched_next
+        assert of["train.read_loss", n][1] >= dispatched_next
+
+    def bounds(name, n):
+        """The span's host seconds, and those between its neighbours."""
+        i = phases.index(of[name, n])
+        lo = phases[i - 1][2] if i else steps[0][1]
+        hi = phases[i + 1][1] if i + 1 < len(phases) else steps[-1][2]
+        return (phases[i][2] - phases[i][1]) * 1e-9, (hi - lo) * 1e-9
+
+    def agree(counter, *parts):
+        inner = sum(bounds(name, n)[0] for name, n in parts)
+        outer = sum(bounds(name, n)[1] for name, n in parts)
+        # the span's clock and the counter's may differ by their rates
+        assert inner * (1 - 1e-3) - 1e-6 <= counter <= outer * (1 + 1e-3) + 1e-6
+
+    for m in t.metrics[1:]:
+        n = m.step
+        agree(m.prepare_s, ("train.prepare", n))
+        agree(m.dispatch_s, ("train.dispatch", n))
+        agree(m.compute_s, ("train.dispatch", n), ("train.block", n), ("train.read_loss", n))
     for m in t.metrics:
         assert m.prepare_s >= 0 and 0 <= m.dispatch_s <= m.compute_s
+
+
+class _Watched:
+    """The trainer's loader, recording at each batch request how many steps
+    the trainer has read and a host copy of its parameters and moments, and
+    every batch it hands over."""
+
+    def __init__(self, trainer):
+        self._loader, self._trainer = trainer.loader, trainer
+        self.read_at_request, self.state_at_request, self.batches = [], [], []
+        trainer.loader = self
+
+    def __getattr__(self, name):
+        return getattr(self._loader, name)
+
+    def __iter__(self):
+        batches = iter(self._loader)
+        while True:
+            t = self._trainer
+            self.read_at_request.append(len(t.metrics))
+            self.state_at_request.append([np.array(x) for x in jax.tree.leaves((t.params, t.opt_state))])
+            batch = next(batches, None)
+            if batch is None:
+                return
+            self.batches.append(batch)
+            yield batch
+
+
+def test_the_next_batch_is_requested_before_the_step_is_read():
+    """Batch n+1 is requested while step n is in flight: at request k >= 2
+    the trainer has read k-2 steps.  ``train(k)`` requests k batches and no
+    more, so the loader's cursor is where k steps leave it."""
+    t, svc = _trainer()
+    watched = _Watched(t)
+    with svc:
+        t.train(6)
+        assert watched.read_at_request == [0, 0, 1, 2, 3, 4]
+        assert t.loader.state_dict()["cursor"] == 6 * BATCH
+        t.train(2)
+    assert watched.read_at_request == [0, 0, 1, 2, 3, 4, 6, 6]
+    assert len(watched.batches) == 8 and t.step == 8
+    assert t.loader.state_dict()["cursor"] == 8 * BATCH
+
+
+def test_overlapped_steps_equal_the_serial_steps():
+    """Six steps run one ahead give the losses, the final state and, at each
+    batch request, the state after the steps before it, bit for bit, of the
+    same jitted step applied in sequence to the same batches."""
+    t, svc = _trainer()
+    state = jax.tree.map(np.array, (t.params, t.opt_state))
+    watched = _Watched(t)
+    with svc:
+        t.train(6)
+    params, opt = jax.tree.map(jnp.asarray, state)
+    losses = []
+    for held, batch in zip(watched.state_at_request, watched.batches):
+        serial = [np.asarray(x) for x in jax.tree.leaves((params, opt))]
+        assert [a.tobytes() for a in held] == [b.tobytes() for b in serial]
+        (loss, _), params, opt = t._step_fn(params, opt, t._to_device_batch(batch))
+        losses.append(float(loss))
+    assert [m.loss for m in t.metrics] == losses
+    for a, b in zip(jax.tree.leaves((t.params, t.opt_state)), jax.tree.leaves((params, opt))):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+def test_the_loop_drains_before_a_checkpoint_and_at_the_last_step():
+    """``overlapped`` is False where the loop read the step before requesting
+    the next batch: a checkpoint step and the call's last step.  It is True
+    elsewhere, also at an epoch's last step, whose next request (the one
+    that finds the epoch's end) went out while it ran."""
+    d = tempfile.mkdtemp()
+    t, svc = _trainer(ckpt_dir=d, every=5, n_samples=64)  # 16 batches an epoch
+    with svc:
+        metrics = t.train(18, epochs=2)
+    assert [m.step for m in metrics] == list(range(1, 19))
+    assert [m.step for m in metrics if not m.overlapped] == [5, 10, 15, 18]
+    assert ckpt.list_steps(d)[-1] == 15
+    for m in metrics:
+        assert m.prepare_s >= 0 and 0 <= m.dispatch_s <= m.compute_s
+
+
+class _FailsAtFourthRequest(_Watched):
+    def __iter__(self):
+        for k, batch in enumerate(super().__iter__(), 1):
+            if k == 4:
+                raise OSError("the bucket is unreachable")
+            yield batch
+
+
+def test_an_exception_drains_the_step_in_flight():
+    """An exception that leaves the loop leaves it after the step in flight
+    is read: the loader failing at request 4 leaves three steps read, the
+    third not overlapped, and the state after them alive."""
+    t, svc = _trainer()
+    _FailsAtFourthRequest(t)
+    with svc, pytest.raises(OSError):
+        t.train(6)
+    assert t.step == 3
+    assert [m.overlapped for m in t.metrics] == [True, True, False]
+    assert not any(x.is_deleted() for x in jax.tree.leaves((t.params, t.opt_state)))
 
 
 def test_epoch_end_stops_the_call_as_before():
